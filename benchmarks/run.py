@@ -59,10 +59,9 @@ def bench_stream() -> float:
 
 
 def bench_calibrate(beta: float) -> None:
-    import dataclasses
+    from benchmarks.spmm_suite import planning_hardware
     from repro.core.calibrate import CalibrationStore, calibrate
-    from repro.core.hardware import HOST_CPU
-    hw = dataclasses.replace(HOST_CPU, hbm_bandwidth=beta)
+    hw = planning_hardware(beta)
     store = CalibrationStore()
     t0 = time.perf_counter()
     cal = calibrate(hw, backend="jax", store=store)
@@ -296,6 +295,8 @@ def main() -> None:
                              "ceilings before (or instead of) the suites; "
                              "subsequent dispatcher predictions use them")
     args = parser.parse_args()
+    from repro.launch.compile_cache import configure
+    configure()
     print("name,us_per_call,derived")
     beta = bench_stream()
     if args.calibrate:

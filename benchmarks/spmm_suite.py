@@ -23,7 +23,7 @@ import numpy as np
 
 from repro import sparse
 from repro.configs.paper_spmm import CONFIG as SPMM_CONFIG
-from repro.core.hardware import HOST_CPU
+from repro.core.hardware import HOST_CPU, HardwareSpec, device_hardware
 from repro.core.patterns import paper_suite
 
 
@@ -53,10 +53,18 @@ class CellResult:
     dtype: str = "f32i32"        # storage-precision token the cell ran at
 
 
+def planning_hardware(beta: float) -> HardwareSpec:
+    """The default device's spec; on the CPU, with the STREAM-measured
+    bandwidth ``beta`` (host STREAM says nothing about a chip's HBM)."""
+    hw = device_hardware()
+    if hw is HOST_CPU:
+        hw = dataclasses.replace(HOST_CPU, hbm_bandwidth=beta)
+    return hw
+
+
 def make_dispatcher(beta: float, **kwargs) -> sparse.Dispatcher:
-    """Dispatcher whose roofline uses the measured STREAM bandwidth."""
-    hw = dataclasses.replace(HOST_CPU, hbm_bandwidth=beta)
-    return sparse.Dispatcher(hardware=hw, **kwargs)
+    """Dispatcher planning on :func:`planning_hardware`."""
+    return sparse.Dispatcher(hardware=planning_hardware(beta), **kwargs)
 
 
 def run_suite(beta: float, scale: int | None = None,

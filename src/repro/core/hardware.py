@@ -3,6 +3,10 @@
 The paper's testbed is one socket of an AMD EPYC 7763 (Perlmutter CPU node);
 our deployment target is a TPU v5e pod slice.  Both are expressed with the
 same dataclass so every roofline routine is hardware-agnostic.
+
+:func:`for_device_kind` is the one place a running device is mapped to its
+spec (``DEVICE_KINDS``, keyed by ``jax.Device.device_kind``); a device kind
+missing from the table is an error, never a default.
 """
 from __future__ import annotations
 
@@ -20,6 +24,7 @@ class HardwareSpec:
     hbm_bandwidth: float       # bytes/s main-memory bandwidth (per device)
     link_bandwidth: float      # bytes/s per inter-device link (0 => none)
     vmem_bytes: int = 0        # software-managed fast memory (VMEM / LLC)
+    smem_bytes: int = 0        # scalar memory (TPU SMEM; 0 = no limit)
     hbm_bytes: int = 0         # main memory capacity per device
     mxu_tile: tuple = (128, 128)  # native matmul tile (rows, cols)
     #: Aggregate interconnect bandwidth one device can drive during a
@@ -82,13 +87,16 @@ PERLMUTTER_MILAN = HardwareSpec(
     mxu_tile=(1, 4),                  # AVX2 dp vector as the "tile"
 )
 
-# --- Deployment target: TPU v5e (per chip), constants from the task spec. ---
+# --- Deployment target: TPU v5e (per chip).  Peaks: Google Cloud
+# documentation, "TPU v5e" (197 TFLOP/s bf16, 16 GB HBM at 819 GB/s,
+# 1,600 Gbit/s ICI); 128 MiB of VMEM per TensorCore. ---
 TPU_V5E = HardwareSpec(
     name="tpu-v5e",
     peak_flops=197e12,                # bf16
     hbm_bandwidth=819e9,
     link_bandwidth=50e9,              # per ICI link
     vmem_bytes=128 * 2**20,
+    smem_bytes=2**20,                 # the compiler's SMEM capacity
     hbm_bytes=16 * 2**30,
     mxu_tile=(128, 128),
     ici_bytes_per_s=4 * 50e9,         # 4 ICI links per chip (2D torus)
@@ -109,6 +117,55 @@ HOST_CPU = HardwareSpec(
     # collective_bandwidth falls back to hbm_bandwidth (ici stays 0).
     collective_latency_s=20e-6,
 )
+
+
+#: ``jax.Device.device_kind`` -> spec.  "cpu" is the CPU backend the
+#: tests run on (Pallas kernels in interpret mode).
+DEVICE_KINDS = {
+    "TPU v5 lite": TPU_V5E,
+    "cpu": HOST_CPU,
+}
+
+
+def for_device_kind(kind: str) -> HardwareSpec:
+    """The spec of a device reporting ``device_kind == kind``.
+
+    Raises:
+        ValueError: for a kind that is not in ``DEVICE_KINDS``: planning
+            an unknown chip with another chip's ceilings would be wrong.
+    """
+    try:
+        return DEVICE_KINDS[kind]
+    except KeyError:
+        raise ValueError(
+            f"no HardwareSpec for device kind {kind!r}; known kinds: "
+            f"{sorted(DEVICE_KINDS)}") from None
+
+
+def device_hardware() -> HardwareSpec:
+    """The spec of JAX's default device (``jax.devices()[0]``)."""
+    import jax
+    return for_device_kind(jax.devices()[0].device_kind)
+
+
+def kernel_vmem_limit(hw: HardwareSpec) -> int:
+    """Scoped VMEM every Pallas kernel requests (``vmem_limit_bytes``).
+
+    Half of the physical VMEM: the rest stays with Mosaic's own scratch.
+    Every resident block a kernel holds, double-buffered, is sized from
+    this budget, and the dispatcher skips a Pallas candidate whose
+    modelled footprint exceeds it.
+    """
+    return hw.vmem_bytes // 2
+
+
+def kernel_smem_limit(hw: HardwareSpec) -> int:
+    """SMEM a Pallas kernel's scalar-prefetched metadata may take.
+
+    Three quarters of SMEM (the rest holds the kernels' scalar scratch);
+    0 where the spec states no SMEM size (no limit is checked).
+    """
+    return hw.smem_bytes * 3 // 4
 
 
 def by_name(name: str) -> HardwareSpec:

@@ -9,6 +9,8 @@ B tiles, B is streamed HBM->VMEM essentially once — the TPU counterpart of
 A is stored densely as ``band[nb, W, t, t]`` with W = 2w+1; edge blocks are
 zero-padded so index maps never need masking (a zero block contributes
 nothing while the clamped B tile it multiplies is already resident).
+The matmul runs at ``Precision.HIGHEST`` so fp32 blocks are not rounded to
+bf16 on the MXU.
 """
 from __future__ import annotations
 
@@ -17,6 +19,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.csr_spmm import mxu_precision
 
 
 def _banded_kernel(a_ref, b_ref, o_ref, *, w: int):
@@ -28,14 +33,16 @@ def _banded_kernel(a_ref, b_ref, o_ref, *, w: int):
         o_ref[...] = jnp.zeros_like(o_ref)
 
     o_ref[...] += jnp.dot(a_ref[0, 0], b_ref[...],
+                          precision=mxu_precision(a_ref.dtype),
                           preferred_element_type=jnp.float32)
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("t", "w", "block_d", "interpret"))
+                   static_argnames=("t", "w", "block_d", "vmem_limit",
+                                    "interpret"))
 def banded_spmm_pallas(band: jnp.ndarray, b: jnp.ndarray, *, t: int, w: int,
-                       block_d: int = 512,
-                       interpret: bool = True) -> jnp.ndarray:
+                       block_d: int, vmem_limit: int,
+                       interpret: bool) -> jnp.ndarray:
     """C = A @ B for banded A.
 
     Args:
@@ -43,6 +50,9 @@ def banded_spmm_pallas(band: jnp.ndarray, b: jnp.ndarray, *, t: int, w: int,
             block position (i, i + o - w), zero where out of range.
       b:    [n, d] dense operand; n = nb * t.
       t, w: block edge and half-width in blocks (static).
+      block_d: d-tile width (static).
+      vmem_limit: scoped VMEM the kernel may use, in bytes (static).
+      interpret: run in Pallas interpret mode (the CPU test path).
     """
     nb, W, _, _ = band.shape
     assert W == 2 * w + 1, (W, w)
@@ -72,6 +82,8 @@ def banded_spmm_pallas(band: jnp.ndarray, b: jnp.ndarray, *, t: int, w: int,
         ],
         out_specs=pl.BlockSpec((t, bd), o_map),
         out_shape=jax.ShapeDtypeStruct((n, d), jnp.float32),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit),
         interpret=interpret,
+        name="banded_spmm",
     )(band, b)
     return out.astype(b.dtype)

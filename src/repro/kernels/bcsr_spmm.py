@@ -15,7 +15,9 @@ VMEM working set per grid step:
     A block  t*t*4           (e.g. 128x128 fp32 = 64 KiB)
     B tile   t*bd*4          (128x512     fp32 = 256 KiB)
     C tile   t*bd*4          (128x512     fp32 = 256 KiB)
-well under the ~128 MiB v5e VMEM; t and bd default to MXU-aligned 128/512.
+double-buffered, well under the kernel's scoped VMEM limit; t and bd
+default to MXU-aligned 128/512.  The matmul runs at ``Precision.HIGHEST``
+so fp32 blocks are not rounded to bf16 on the MXU.
 """
 from __future__ import annotations
 
@@ -26,6 +28,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.csr_spmm import mxu_precision
+
 
 def _bcsr_kernel(rows_ref, cols_ref, a_ref, b_ref, o_ref):
     """One grid step: o[rows[i]] += a[i] @ b[cols[i]] (accumulated in VMEM)."""
@@ -33,7 +37,8 @@ def _bcsr_kernel(rows_ref, cols_ref, a_ref, b_ref, o_ref):
     i_n = pl.program_id(1)
     # First visit of this C tile in this d-pass: previous block was a
     # different block row (or this is the first block).
-    is_first = (i_n == 0) | (rows_ref[i_n] != rows_ref[i_n - 1])
+    is_first = (i_n == 0) | (rows_ref[i_n] != rows_ref[jnp.maximum(i_n - 1,
+                                                                   0)])
 
     @pl.when(is_first)
     def _zero():
@@ -42,14 +47,16 @@ def _bcsr_kernel(rows_ref, cols_ref, a_ref, b_ref, o_ref):
     a_block = a_ref[0]                      # [t, t]
     b_tile = b_ref[...]                     # [t, bd]
     o_ref[...] += jnp.dot(a_block, b_tile,
+                          precision=mxu_precision(a_block.dtype),
                           preferred_element_type=jnp.float32)
 
 
-@functools.partial(jax.jit, static_argnames=("n", "t", "block_d", "interpret"))
+@functools.partial(jax.jit, static_argnames=("n", "t", "block_d",
+                                             "vmem_limit", "interpret"))
 def bcsr_spmm_pallas(blocks: jnp.ndarray, block_rows: jnp.ndarray,
                      block_cols: jnp.ndarray, b: jnp.ndarray, *, n: int,
-                     t: int, block_d: int = 512,
-                     interpret: bool = True) -> jnp.ndarray:
+                     t: int, block_d: int, vmem_limit: int,
+                     interpret: bool) -> jnp.ndarray:
     """C = A @ B with A given as sorted nonzero blocks.
 
     Args:
@@ -61,7 +68,8 @@ def bcsr_spmm_pallas(blocks: jnp.ndarray, block_rows: jnp.ndarray,
       b:          [n, d] dense operand.
       n, t:       matrix dim and block edge (static).
       block_d:    d-tile width (static, MXU-aligned).
-      interpret:  run in interpret mode (CPU correctness path).
+      vmem_limit: scoped VMEM the kernel may use, in bytes (static).
+      interpret:  run in Pallas interpret mode (the CPU test path).
     """
     d = b.shape[1]
     bd = min(block_d, d)
@@ -86,6 +94,8 @@ def bcsr_spmm_pallas(blocks: jnp.ndarray, block_rows: jnp.ndarray,
         _bcsr_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((nb * t, d), jnp.float32),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit),
         interpret=interpret,
+        name="bcsr_spmm",
     )(block_rows, block_cols, blocks, b)
     return out[:n].astype(b.dtype)

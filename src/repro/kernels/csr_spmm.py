@@ -1,4 +1,4 @@
-"""Pallas TPU kernel: CSR row-gather / segment-sum SpMM with a streamed B.
+"""Pallas TPU kernel: CSR row-gather / segment-sum SpMM with B left in HBM.
 
 TPU realization of the paper's CSR baseline (the random-regime
 implementation): every nonzero gathers its row of B and the products are
@@ -8,39 +8,43 @@ segment sum becomes an MXU matmul:
   * rows are grouped into tiles of ``row_tile`` rows; each tile's nonzeros
     are padded to whole chunks of ``chunk`` entries (sliced-ELL style
     packing of the CSR arrays, built host-side by ``csr_to_row_tiles``);
-  * one grid step processes one chunk: it gathers ``chunk`` rows of B from
-    the VMEM-resident slab, scales by the nonzero values, and reduces
-    into the tile's C block with a one-hot [row_tile, chunk] matmul — the
-    segment-sum expressed as MXU work instead of scatter traffic;
-  * chunk -> row-tile ownership arrives via scalar prefetch (like the BCSR
-    kernel's block coordinates), so the C tile stays resident in VMEM for
-    all chunks of a tile and is written exactly once.
+  * one grid step owns one row tile's C block and loops over the tile's
+    chunks (their range arrives via scalar prefetch, one int per tile:
+    SMEM holds 1 MiB, too little for one int per chunk at n = 2**20);
+  * per chunk, the column ids are DMA'd into SMEM, the ``chunk`` rows of
+    B they name are DMA'd straight from HBM into a VMEM gather buffer,
+    and one ``[row_tile, chunk] @ [chunk, bd]`` matmul — its left operand
+    the value-weighted one-hot of the nonzeros' row slots — reduces them
+    into the tile.
 
-B streaming (propagation-blocking style, Gu et al. 2020): the gather
-targets are data-dependent, so no index map could stream B row-by-row —
-but the *host* can.  ``csr_to_row_tiles`` optionally groups each row
-tile's nonzeros by the B row slab they gather from (``b_tile`` rows per
-slab) and records the slab id per chunk.  The kernel's B BlockSpec then
-covers one ``[b_tile, bd]`` slab, selected per chunk through scalar
-prefetch, and column indices are stored slab-local.  VMEM now holds one
-slab instead of all of B, so the kernel scales past the old
-``n * bd * 4 <= VMEM`` bound; with ``b_tile=None`` (one slab spanning all
-rows) the layout and kernel reduce exactly to the unstreamed original.
+B never has to fit VMEM: only the ``[chunk, bd]`` gather buffer is
+resident, so the layout needs no B slabs and column ids stay global.
 
-Padding slots carry value 0 (and column/row-slot 0), so they contribute
-nothing; every row tile owns at least one chunk, so every C block is
-visited and zeroed even for empty rows.
+The gathered rows are fp32 (B is upcast before the call): Mosaic cannot
+address single rows of a packed bf16 tile.  bf16 precisions still store
+values at bf16; accumulation is fp32 throughout, and the matmuls run at
+``Precision.HIGHEST`` so fp32 operands are not rounded to bf16 on the MXU.
+
+Index and value chunks are ``[C / p, p, chunk]`` arrays with ``p`` chunks
+per 32-bit sublane row (``chunks_per_row``), so each chunk is fetched by
+one tile-aligned DMA at its storage width; row slots are int8
+(``row_tile <= 128``).  Padding slots carry value 0 and slot 0, so they
+contribute nothing; every tile's C block is zeroed before its chunks, so
+empty rows come out zero.
 """
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+#: Row slots are stored int8, so an output tile holds at most 128 rows.
+MAX_ROW_TILE = 128
 
 
 def index_extent_check(extent: int, index_dtype) -> None:
@@ -56,175 +60,255 @@ def index_extent_check(extent: int, index_dtype) -> None:
             f"(max {2 ** 15 - 1} including the sentinel slot)")
 
 
+def pack_chunks(group_of_nz: np.ndarray, num_groups: int, chunk: int
+                ) -> Tuple[np.ndarray, np.ndarray, int]:
+    """Place a group-sorted nonzero stream into whole chunks per group.
+
+    ``group_of_nz`` is non-decreasing; group ``g`` gets
+    ``ceil(count / chunk)`` chunks.  Returns ``(starts[G + 1], pos[nnz],
+    num_chunks)``: group ``g`` owns chunks ``[starts[g], starts[g + 1])``
+    and each nonzero's slot in the flattened ``[C * chunk]`` layout.  An
+    empty stream still gets one (all-padding) chunk so every array has a
+    well-formed shape.
+    """
+    counts = np.bincount(group_of_nz, minlength=num_groups)
+    starts = np.concatenate([[0], np.cumsum(-(-counts // chunk))])
+    first_nz = np.concatenate([[0], np.cumsum(counts)])
+    rank = np.arange(group_of_nz.shape[0]) - first_nz[group_of_nz]
+    pos = starts[group_of_nz] * chunk + rank
+    return starts.astype(np.int32), pos, max(1, int(starts[-1]))
+
+
+def chunks_per_row(dtype) -> int:
+    """Chunks stored per sublane row of a packed chunk array.
+
+    A 32-bit sublane holds one element of a 32-bit dtype, two of a 16-bit
+    and four of an 8-bit one.  Storing ``[C / p, p, chunk]`` makes one
+    chunk-row a whole HBM tile, so a chunk is fetched with one aligned DMA
+    and a narrow dtype is not padded out to 32 bits.
+    """
+    return max(1, 4 // np.dtype(dtype).itemsize)
+
+
+def scatter_chunks(pos: np.ndarray, num_chunks: int, chunk: int,
+                   *arrays: Tuple[np.ndarray, object]):
+    """Zero-filled ``[C / p, p, chunk]`` arrays with ``values`` at ``pos``.
+
+    Chunk ``c`` of each array is row ``c % p`` of its entry ``c // p``
+    (``p = chunks_per_row(dtype)``).
+    """
+    out = []
+    for values, dtype in arrays:
+        p = chunks_per_row(dtype)
+        rows = -(-num_chunks // p)
+        flat = np.zeros(rows * p * chunk, dtype=dtype)
+        flat[pos] = values
+        out.append(flat.reshape(rows, p, chunk))
+    return out
+
+
 def csr_to_row_tiles(indptr: np.ndarray, indices: np.ndarray,
-                     data: np.ndarray, *, n: int, row_tile: int = 8,
-                     chunk: int = 128,
-                     b_tile: Optional[int] = None,
-                     index_dtype=np.int32
+                     data: np.ndarray, *, n: int, row_tile: int = 32,
+                     chunk: int = 128, index_dtype=np.int32
                      ) -> Tuple[np.ndarray, np.ndarray, np.ndarray,
-                                np.ndarray, np.ndarray]:
+                                np.ndarray]:
     """Pack CSR arrays into fixed-size chunks grouped by row tile.
 
-    Returns ``(tile_ids[C], b_tile_ids[C], cols[C, chunk],
-    row_slots[C, chunk], vals[C, chunk])`` where chunk ``c`` belongs to row
-    tile ``tile_ids[c]``, gathers only from B row slab ``b_tile_ids[c]``,
-    and ``row_slots`` are row indices *within* the tile.  Chunks of a tile
-    are contiguous; empty tiles still get one all-zero chunk.
-
-    With ``b_tile=None`` there is a single slab spanning all rows:
-    ``b_tile_ids`` is all zeros and ``cols`` are global row indices of B.
-    With ``b_tile=bt`` each row tile's nonzeros are partitioned by
-    ``col // bt`` (ascending slab order) and ``cols`` become slab-local
-    (``col - slab * bt``), so the kernel only needs one ``[bt, bd]`` slab
-    of B resident per chunk.
-
-    ``cols``/``row_slots`` are stored at ``index_dtype``: with slab
-    streaming the addressed extent is only ``b_tile`` rows, so int16
-    columns are legal whenever the slab height fits (the kernel upcasts
-    after the VMEM load — traffic is paid at the compact width).
+    Returns ``(tile_starts[T + 1], cols, row_slots, vals)``, the last
+    three chunk arrays as ``scatter_chunks`` lays them out: row tile ``t``
+    owns chunks
+    ``[tile_starts[t], tile_starts[t + 1])`` (none for an empty tile),
+    ``cols`` are global column ids of B at ``index_dtype`` and
+    ``row_slots`` (int8) are row indices *within* the tile.  Padding is
+    under one chunk per row tile.
     """
-    indptr = np.asarray(indptr)
-    indices = np.asarray(indices)
-    data = np.asarray(data)
-    index_extent_check(n if b_tile is None else b_tile, index_dtype)
+    if not 0 < row_tile <= MAX_ROW_TILE:
+        raise ValueError(f"row_tile must be in [1, {MAX_ROW_TILE}], "
+                         f"got {row_tile}")
+    indptr = np.asarray(indptr, dtype=np.int64)
+    nnz = int(indptr[-1])
+    index_extent_check(n, index_dtype)
     num_tiles = (n + row_tile - 1) // row_tile
-    tile_ids, slab_ids, cols_c, slots_c, vals_c = [], [], [], [], []
-
-    def emit(tile: int, slab: int, cols: np.ndarray, slots: np.ndarray,
-             vals: np.ndarray) -> None:
-        cnt = cols.shape[0]
-        n_chunks = max(1, -(-cnt // chunk))
-        c = np.zeros(n_chunks * chunk, dtype=index_dtype)
-        s = np.zeros(n_chunks * chunk, dtype=index_dtype)
-        v = np.zeros(n_chunks * chunk, dtype=data.dtype)
-        c[:cnt] = cols
-        s[:cnt] = slots
-        v[:cnt] = vals
-        tile_ids.extend([tile] * n_chunks)
-        slab_ids.extend([slab] * n_chunks)
-        cols_c.append(c.reshape(n_chunks, chunk))
-        slots_c.append(s.reshape(n_chunks, chunk))
-        vals_c.append(v.reshape(n_chunks, chunk))
-
-    for tile in range(num_tiles):
-        r0 = tile * row_tile
-        r1 = min(r0 + row_tile, n)
-        lo, hi = int(indptr[r0]), int(indptr[r1])
-        cols = indices[lo:hi].astype(np.int64)
-        vals = data[lo:hi]
-        row_of_nz = np.repeat(np.arange(r0, r1),
-                              np.diff(indptr[r0:r1 + 1]).astype(np.int64))
-        slots = (row_of_nz - r0).astype(index_dtype)
-        if b_tile is None:
-            emit(tile, 0, cols.astype(index_dtype), slots, vals)
-            continue
-        slabs = cols // b_tile
-        if cols.shape[0] == 0:
-            emit(tile, 0, cols.astype(index_dtype), slots, vals)
-            continue
-        # Stable partition by slab: chunks of a tile stay contiguous and
-        # visit slabs in ascending order (sequential-ish B traffic).
-        order = np.argsort(slabs, kind="stable")
-        cols, vals, slots, slabs = (cols[order], vals[order], slots[order],
-                                    slabs[order])
-        bounds = np.flatnonzero(np.diff(slabs)) + 1
-        for seg_cols, seg_slots, seg_vals, seg_slabs in zip(
-                np.split(cols, bounds), np.split(slots, bounds),
-                np.split(vals, bounds), np.split(slabs, bounds)):
-            slab = int(seg_slabs[0])
-            emit(tile, slab, (seg_cols - slab * b_tile).astype(index_dtype),
-                 seg_slots, seg_vals)
-    return (np.asarray(tile_ids, dtype=np.int32),
-            np.asarray(slab_ids, dtype=np.int32),
-            np.concatenate(cols_c), np.concatenate(slots_c),
-            np.concatenate(vals_c))
+    rows = np.repeat(np.arange(n, dtype=np.int64), np.diff(indptr))
+    tiles = rows // row_tile
+    starts, pos, num_chunks = pack_chunks(tiles, num_tiles, chunk)
+    cols, slots, vals = scatter_chunks(
+        pos, num_chunks, chunk,
+        (np.asarray(indices)[:nnz], index_dtype),
+        (rows - tiles * row_tile, np.int8),
+        (np.asarray(data)[:nnz], np.asarray(data).dtype))
+    return starts, cols, slots, vals
 
 
-def _csr_kernel(tiles_ref, slabs_ref, cols_ref, slots_ref, vals_ref, b_ref,
-                o_ref, *, row_tile: int):
-    """One grid step: gather-scale one chunk, one-hot-matmul into its C tile."""
-    del slabs_ref  # consumed by the B index map
-    i_c = pl.program_id(1)
-    # First chunk of this row tile in this d-pass: zero the resident C block.
-    is_first = (i_c == 0) | (tiles_ref[i_c] != tiles_ref[i_c - 1])
+def mxu_precision(dtype):
+    """``HIGHEST`` for fp32 operands, else the default: an fp32 matmul at
+    default precision rounds its operands to bf16 on the MXU, and Mosaic
+    refuses ``HIGHEST`` on operands that already are bf16."""
+    return jax.lax.Precision.HIGHEST if jnp.dtype(dtype) == jnp.float32 \
+        else jax.lax.Precision.DEFAULT
 
-    @pl.when(is_first)
-    def _zero():
-        o_ref[...] = jnp.zeros_like(o_ref)
 
-    # Indices may be stored int16 (compact-index precisions); the HBM/VMEM
-    # traffic is paid at that width and the gather wants int32.
-    cols = cols_ref[0].astype(jnp.int32)             # [chunk] slab-local
-    slots = slots_ref[0].astype(jnp.int32)           # [chunk]
-    vals = vals_ref[0]                               # [chunk]
-    gathered = b_ref[...][cols]                      # [chunk, bd] row gather
-    scaled = gathered * vals[:, None]
-    # Segment sum as a matmul: onehot[r, j] = (slots[j] == r).
-    rows = jax.lax.broadcasted_iota(jnp.int32, (row_tile, cols.shape[0]), 0)
-    onehot = (rows == slots[None, :]).astype(scaled.dtype)
-    o_ref[...] += jnp.dot(onehot, scaled,
-                          preferred_element_type=jnp.float32)
+def chunk_row(ref, c) -> jnp.ndarray:
+    """Chunk ``c``'s ``[1, chunk]`` row of a loaded ``[1, p, chunk]`` entry,
+    widened to 32 bits (static slices of the widened tile, then a select:
+    Mosaic cannot address one row of a packed tile dynamically)."""
+    p = ref.shape[1]
+    wide = jnp.float32 if jnp.issubdtype(ref.dtype, jnp.floating) \
+        else jnp.int32
+    full = ref[0].astype(wide)                                 # [p, chunk]
+    row = full[0:1]
+    for i in range(1, p):
+        row = jnp.where(c % p == i, full[i:i + 1], row)
+    return row
+
+
+def chunk_product(c, slots_ref, vals_ref, gbuf, rows: int) -> jnp.ndarray:
+    """``[rows, bd]`` partial of chunk ``c``: value-weighted one-hot @ rows.
+
+    ``w[r, j] = vals[j]`` where ``slots[j] == r``: the segment sum by row
+    slot expressed as one MXU matmul over the gathered rows in ``gbuf``.
+    """
+    slots = chunk_row(slots_ref, c)                          # [1, chunk]
+    vals = chunk_row(vals_ref, c)                            # [1, chunk]
+    slot_ids = jax.lax.broadcasted_iota(jnp.int32, (rows, slots.shape[1]), 0)
+    w = jnp.where(slot_ids == slots, vals, 0.0)
+    return jnp.dot(w, gbuf[...], precision=jax.lax.Precision.HIGHEST,
+                   preferred_element_type=jnp.float32)
+
+
+def chunk_col(cols_ref, c, j) -> jnp.ndarray:
+    """Column id ``j`` of chunk ``c`` from its SMEM ``[1, p, chunk]`` entry."""
+    return cols_ref[0, c % cols_ref.shape[1], j].astype(jnp.int32)
+
+
+def gather_from_vmem(c, cols_ref, b_ref, gbuf) -> None:
+    """``gbuf[j] = b_ref[cols[j]]`` from a VMEM-resident block of B."""
+    def body(j, carry):
+        gbuf[pl.ds(j, 1), :] = b_ref[pl.ds(chunk_col(cols_ref, c, j), 1), :]
+        return carry
+    jax.lax.fori_loop(0, gbuf.shape[0], body, 0)
+
+
+def chunk_spec(array, index_map, smem: bool = False) -> pl.BlockSpec:
+    """BlockSpec of one ``[1, p, chunk]`` entry of a packed chunk array;
+    ``index_map`` gives the entry index."""
+    shape = (1,) + tuple(array.shape[1:])
+    full = lambda *idx: (index_map(*idx), 0, 0)   # noqa: E731
+    if smem:
+        return pl.BlockSpec(shape, full, memory_space=pltpu.SMEM)
+    return pl.BlockSpec(shape, full)
+
+
+def chunk_scratch(cols, slots, vals) -> list:
+    """Scratch for one entry of the columns (SMEM), slots and values."""
+    return [pltpu.SMEM((1,) + tuple(cols.shape[1:]), cols.dtype),
+            pltpu.VMEM((1,) + tuple(slots.shape[1:]), slots.dtype),
+            pltpu.VMEM((1,) + tuple(vals.shape[1:]), vals.dtype)]
+
+
+def load_chunk(c, hbm_refs, bufs, sems) -> None:
+    """DMA the entries holding chunk ``c`` of the (cols, slots, vals)."""
+    copies = [pltpu.make_async_copy(
+        src.at[pl.ds(c // src.shape[1], 1)], dst, sems.at[i])
+        for i, (src, dst) in enumerate(zip(hbm_refs, bufs))]
+    for cp in copies:
+        cp.start()
+    for cp in copies:
+        cp.wait()
+
+
+def for_each_chunk(owner: int, starts_ref, body) -> None:
+    """Run ``body(c)`` for the chunks ``[starts[owner], starts[owner+1])``."""
+    def step(c, carry):
+        body(c)
+        return carry
+    jax.lax.fori_loop(starts_ref[owner], starts_ref[owner + 1], step, 0)
+
+
+def _csr_kernel(starts_ref, cols_hbm, slots_hbm, vals_hbm, b_hbm, o_ref,
+                cols_buf, slots_buf, vals_buf, gbuf, sems, *,
+                row_tile: int):
+    """One grid step: one row tile's C block, its chunks in a loop.
+
+    Each chunk's metadata is DMA'd into SMEM/VMEM, then its B rows are
+    DMA'd from HBM into ``gbuf`` and reduced into the tile.
+    """
+    tile = pl.program_id(1)
+    bd = o_ref.shape[1]
+    d0 = pl.program_id(0) * bd
+    o_ref[...] = jnp.zeros_like(o_ref)
+
+    def row_copy(col, j):
+        return pltpu.make_async_copy(b_hbm.at[pl.ds(col, 1), pl.ds(d0, bd)],
+                                     gbuf.at[pl.ds(j, 1), :], sems.at[3])
+
+    def chunk(c):
+        load_chunk(c, (cols_hbm, slots_hbm, vals_hbm),
+                   (cols_buf, slots_buf, vals_buf), sems)
+
+        def start(j, carry):
+            row_copy(chunk_col(cols_buf, c, j), j).start()
+            return carry
+
+        jax.lax.fori_loop(0, gbuf.shape[0], start, 0)
+        jax.lax.fori_loop(0, gbuf.shape[0], wait, 0)
+        o_ref[...] += chunk_product(c, slots_buf, vals_buf, gbuf, row_tile)
+
+    def wait(j, carry):
+        row_copy(0, 0).wait()          # one row's bytes per wait
+        return carry
+
+    for_each_chunk(tile, starts_ref, chunk)
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("n", "row_tile", "b_tile", "block_d",
-                                    "interpret"))
-def csr_spmm_pallas(tile_ids: jnp.ndarray, b_tile_ids: jnp.ndarray,
-                    cols: jnp.ndarray, row_slots: jnp.ndarray,
-                    vals: jnp.ndarray, b: jnp.ndarray, *, n: int,
-                    row_tile: int = 8, b_tile: Optional[int] = None,
-                    block_d: int = 512, interpret: bool = True
-                    ) -> jnp.ndarray:
+                   static_argnames=("n", "row_tile", "block_d",
+                                    "vmem_limit", "interpret"))
+def csr_spmm_pallas(tile_starts: jnp.ndarray, cols: jnp.ndarray,
+                    row_slots: jnp.ndarray, vals: jnp.ndarray,
+                    b: jnp.ndarray, *, n: int, row_tile: int, block_d: int,
+                    vmem_limit: int, interpret: bool) -> jnp.ndarray:
     """C = A @ B with A given as row-tiled CSR chunks (csr_to_row_tiles).
 
     Args:
-      tile_ids:   [C] int32 row-tile id per chunk (non-decreasing).
-      b_tile_ids: [C] int32 B row-slab id per chunk (all zeros when the
-                  layout was packed with ``b_tile=None``).
-      cols:       [C, chunk] column ids (int32 or int16), slab-local,
-                  zero-padded.
-      row_slots:  [C, chunk] row index within the tile (int32 or int16),
-                  zero-padded.
-      vals:       [C, chunk] values, zero-padded.
-      b:          [n, d] dense operand.
-      n:          matrix dimension (static).
-      row_tile:   rows per C tile (static).
-      b_tile:     B rows per VMEM-resident slab (static); must match the
-                  ``b_tile`` the layout was packed with.  None holds B
-                  whole (single slab).
-      block_d:    d-tile width (static).
-      interpret:  run in interpret mode (CPU correctness path).
+      tile_starts: [T + 1] int32 first chunk of each row tile.
+      cols:        [C / p, p, chunk] global column ids (int32 or int16).
+      row_slots:   [C / p, p, chunk] int8 row index within the tile.
+      vals:        [C / p, p, chunk] values, zero-padded (see
+                   ``scatter_chunks`` for the ``p`` chunks per row).
+      b:           [n, d] dense operand; stays in HBM.
+      n:           matrix dimension (static).
+      row_tile:    rows per C tile (static); must match the packing.
+      block_d:     d-tile width (static).
+      vmem_limit:  scoped VMEM the kernel may use, in bytes (static).
+      interpret:   run in Pallas interpret mode (the CPU test path).
     """
+    out_dtype = b.dtype
+    b = b.astype(jnp.float32)
     d = b.shape[1]
     bd = min(block_d, d)
     if d % bd != 0:
         raise ValueError(f"d={d} must be divisible by the d-tile {bd}")
-    bt = b.shape[0] if b_tile is None else b_tile
-    if b.shape[0] % bt != 0:
-        pad = bt - b.shape[0] % bt
-        b = jnp.concatenate([b, jnp.zeros((pad, d), b.dtype)])
-    num_chunks, chunk = cols.shape
-    num_tiles = (n + row_tile - 1) // row_tile
-    grid = (d // bd, num_chunks)
-
+    chunk = cols.shape[2]
+    num_tiles = tile_starts.shape[0] - 1
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, chunk), lambda i_d, i_c, tiles, slabs: (i_c, 0)),
-            pl.BlockSpec((1, chunk), lambda i_d, i_c, tiles, slabs: (i_c, 0)),
-            pl.BlockSpec((1, chunk), lambda i_d, i_c, tiles, slabs: (i_c, 0)),
-            pl.BlockSpec((bt, bd),
-                         lambda i_d, i_c, tiles, slabs: (slabs[i_c], i_d)),
-        ],
+        num_scalar_prefetch=1,
+        grid=(d // bd, num_tiles),
+        in_specs=[hbm, hbm, hbm, hbm],
         out_specs=pl.BlockSpec(
-            (row_tile, bd), lambda i_d, i_c, tiles, slabs: (tiles[i_c], i_d)),
+            (row_tile, bd), lambda i_d, t, starts: (t, i_d)),
+        scratch_shapes=chunk_scratch(cols, row_slots, vals) + [
+            pltpu.VMEM((chunk, bd), jnp.float32),
+            pltpu.SemaphoreType.DMA((4,))],
     )
     out = pl.pallas_call(
         functools.partial(_csr_kernel, row_tile=row_tile),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((num_tiles * row_tile, d),
                                        jnp.float32),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit),
         interpret=interpret,
-    )(tile_ids, b_tile_ids, cols, row_slots, vals, b)
-    return out[:n].astype(b.dtype)
+        name="csr_spmm",
+    )(tile_starts, cols, row_slots, vals, b)
+    return out[:n].astype(out_dtype)

@@ -22,6 +22,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.csr_spmm import mxu_precision
+
 
 def _gmm_kernel(gid_ref, x_ref, w_ref, o_ref):
     del gid_ref  # consumed by the W index map
@@ -32,15 +34,16 @@ def _gmm_kernel(gid_ref, x_ref, w_ref, o_ref):
         o_ref[...] = jnp.zeros_like(o_ref)
 
     o_ref[...] += jnp.dot(x_ref[...], w_ref[0],
+                          precision=mxu_precision(x_ref.dtype),
                           preferred_element_type=jnp.float32)
 
 
 @functools.partial(
-    jax.jit, static_argnames=("bm", "bk", "bn", "interpret"))
+    jax.jit, static_argnames=("bm", "bk", "bn", "vmem_limit", "interpret"))
 def grouped_matmul_pallas(x: jnp.ndarray, w: jnp.ndarray,
                           group_ids: jnp.ndarray, *, bm: int = 128,
-                          bk: int = 128, bn: int = 128,
-                          interpret: bool = True) -> jnp.ndarray:
+                          bk: int = 128, bn: int = 128, vmem_limit: int,
+                          interpret: bool) -> jnp.ndarray:
     """out[r] = x[r] @ w[group_of_row_block(r)].
 
     Args:
@@ -50,6 +53,8 @@ def grouped_matmul_pallas(x: jnp.ndarray, w: jnp.ndarray,
                  block must share an expert (guaranteed by the dispatcher's
                  block-aligned padding).
       bm/bk/bn:  tile sizes (MXU-aligned).
+      vmem_limit: scoped VMEM the kernel may use, in bytes (static).
+      interpret: run in Pallas interpret mode (the CPU test path).
     """
     T, K = x.shape
     E, K2, N = w.shape
@@ -72,6 +77,8 @@ def grouped_matmul_pallas(x: jnp.ndarray, w: jnp.ndarray,
         _gmm_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((T, N), jnp.float32),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit),
         interpret=interpret,
+        name="grouped_matmul",
     )(group_ids, x, w)
     return out.astype(x.dtype)

@@ -20,16 +20,16 @@ from __future__ import annotations
 import warnings
 from typing import Optional
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
 # Re-exported for backward compatibility: these moved to the registry.
 from repro.kernels.registry import (          # noqa: F401
-    KernelRoofline, band_to_blocks, bcsr_kernel_roofline,
-    csr_kernel_roofline, dia_kernel_roofline, grouped_matmul_roofline,
-    pad_empty_block_rows,
+    KernelRoofline, ROW_TILE, band_to_blocks, bcsr_kernel_roofline,
+    csr_kernel_roofline, default_interpret, dia_kernel_roofline,
+    grouped_matmul_roofline, pad_empty_block_rows,
 )
+from repro.core.hardware import device_hardware, kernel_vmem_limit
 from repro.kernels.bcsr_spmm import bcsr_spmm_pallas
 from repro.kernels.banded_spmm import banded_spmm_pallas
 from repro.kernels.binned_spmm import (
@@ -41,7 +41,11 @@ from repro.sparse.formats import BCSRMatrix, CSRMatrix
 
 
 def _interpret(flag: Optional[bool]) -> bool:
-    return (jax.default_backend() != "tpu") if flag is None else flag
+    return default_interpret() if flag is None else flag
+
+
+def _vmem_limit() -> int:
+    return kernel_vmem_limit(device_hardware())
 
 
 def _warn_deprecated(name: str) -> None:
@@ -65,7 +69,7 @@ def bcsr_spmm(a: BCSRMatrix, b: jnp.ndarray, *, block_d: int = 512,
         b: dense right-hand side, [n, d]; when d > ``block_d``, d must be
             a multiple of ``block_d`` (the tile clamps to min(block_d, d)).
         block_d: d-tile width the kernel iterates over.
-        interpret: force Pallas interpret mode; default: off-TPU only.
+        interpret: force Pallas interpret mode; default: CPU backend only.
 
     Returns:
         ``C = A @ B`` as a dense [n, d] array.
@@ -74,12 +78,12 @@ def bcsr_spmm(a: BCSRMatrix, b: jnp.ndarray, *, block_d: int = 512,
     a = pad_empty_block_rows(a)
     return bcsr_spmm_pallas(a.blocks, a.block_rows, a.block_cols, b,
                             n=a.n, t=a.t, block_d=block_d,
+                            vmem_limit=_vmem_limit(),
                             interpret=_interpret(interpret))
 
 
-def csr_spmm(a: CSRMatrix, b: jnp.ndarray, *, row_tile: int = 8,
+def csr_spmm(a: CSRMatrix, b: jnp.ndarray, *, row_tile: int = ROW_TILE,
              chunk: int = 128, block_d: int = 512,
-             b_tile: Optional[int] = None,
              interpret: Optional[bool] = None) -> jnp.ndarray:
     """CSR SpMM via the Pallas row-gather/segment-sum kernel.
 
@@ -91,25 +95,21 @@ def csr_spmm(a: CSRMatrix, b: jnp.ndarray, *, row_tile: int = 8,
         a: CSR container, [n, n].
         b: dense right-hand side, [n, d]; when d > ``block_d``, d must be
             a multiple of ``block_d`` (the tile clamps to min(block_d, d)).
-        row_tile: rows handled per kernel program.
+        row_tile: rows per C tile (at most 128).
         chunk: nonzeros packed per (tile, chunk) slot.
         block_d: d-tile width the kernel iterates over.
-        b_tile: B rows per VMEM-resident slab; None holds B whole.  The
-            dispatcher picks this from ``HardwareSpec.vmem_bytes`` so the
-            kernel streams B past VMEM (``registry.choose_b_tile``).
-        interpret: force Pallas interpret mode; default: off-TPU only.
+        interpret: force Pallas interpret mode; default: CPU backend only.
 
     Returns:
         ``C = A @ B`` as a dense [n, d] array.
     """
     _warn_deprecated("csr_spmm")
-    tiles, slabs, cols, slots, vals = csr_to_row_tiles(
+    arrays = csr_to_row_tiles(
         np.asarray(a.indptr), np.asarray(a.indices), np.asarray(a.data),
-        n=a.n, row_tile=row_tile, chunk=chunk, b_tile=b_tile)
-    return csr_spmm_pallas(jnp.asarray(tiles), jnp.asarray(slabs),
-                           jnp.asarray(cols), jnp.asarray(slots),
-                           jnp.asarray(vals), b, n=a.n, row_tile=row_tile,
-                           b_tile=b_tile, block_d=block_d,
+        n=a.n, row_tile=row_tile, chunk=chunk)
+    return csr_spmm_pallas(*(jnp.asarray(x) for x in arrays), b, n=a.n,
+                           row_tile=row_tile, block_d=block_d,
+                           vmem_limit=_vmem_limit(),
                            interpret=_interpret(interpret))
 
 
@@ -124,17 +124,18 @@ def banded_spmm(band: jnp.ndarray, b: jnp.ndarray, *, t: int, w: int,
         t: block edge; must divide n.
         w: block half-bandwidth (diagonal offsets within ±w*t).
         block_d: d-tile width the kernel iterates over.
-        interpret: force Pallas interpret mode; default: off-TPU only.
+        interpret: force Pallas interpret mode; default: CPU backend only.
 
     Returns:
         ``C = A @ B`` as a dense [n, d] array.
     """
     _warn_deprecated("banded_spmm")
     return banded_spmm_pallas(band, b, t=t, w=w, block_d=block_d,
+                              vmem_limit=_vmem_limit(),
                               interpret=_interpret(interpret))
 
 
-def binned_spmm(a: CSRMatrix, b: jnp.ndarray, *, row_tile: int = 8,
+def binned_spmm(a: CSRMatrix, b: jnp.ndarray, *, row_tile: int = ROW_TILE,
                 chunk: int = 128, block_d: int = 512,
                 b_tile: Optional[int] = None,
                 interpret: Optional[bool] = None) -> jnp.ndarray:
@@ -153,7 +154,7 @@ def binned_spmm(a: CSRMatrix, b: jnp.ndarray, *, row_tile: int = 8,
         chunk: nonzeros packed per kernel step.
         b_tile: B rows per VMEM-resident slab; None holds B whole (one
             slab — degenerates to CSR order).
-        interpret: force Pallas interpret mode; default: off-TPU only.
+        interpret: force Pallas interpret mode; default: CPU backend only.
 
     Returns:
         ``C = A @ B`` as a dense [n, d] array.
@@ -164,7 +165,7 @@ def binned_spmm(a: CSRMatrix, b: jnp.ndarray, *, row_tile: int = 8,
         n=a.n, row_tile=row_tile, chunk=chunk, b_tile=b_tile)
     return binned_spmm_pallas(*(jnp.asarray(x) for x in arrays), b,
                               n=a.n, row_tile=row_tile, b_tile=b_tile,
-                              block_d=block_d,
+                              block_d=block_d, vmem_limit=_vmem_limit(),
                               interpret=_interpret(interpret))
 
 
@@ -183,7 +184,7 @@ def rowsplit_spmm(a: CSRMatrix, b: jnp.ndarray, *, chunk: int = 128,
             trades B residency for perfect load balance).
         chunk: nonzeros per work unit.
         block_d: d-tile width the kernel iterates over.
-        interpret: force Pallas interpret mode; default: off-TPU only.
+        interpret: force Pallas interpret mode; default: CPU backend only.
 
     Returns:
         ``C = A @ B`` as a dense [n, d] array.
@@ -195,7 +196,8 @@ def rowsplit_spmm(a: CSRMatrix, b: jnp.ndarray, *, chunk: int = 128,
     return rowsplit_spmm_pallas(
         jnp.asarray(row_map), jnp.asarray(cols), jnp.asarray(slots),
         jnp.asarray(vals), b, n=a.n, window=int(row_map.shape[1]),
-        block_d=block_d, interpret=_interpret(interpret))
+        block_d=block_d, vmem_limit=_vmem_limit(),
+        interpret=_interpret(interpret))
 
 
 def grouped_matmul(x: jnp.ndarray, w: jnp.ndarray, group_ids: jnp.ndarray,
@@ -208,11 +210,12 @@ def grouped_matmul(x: jnp.ndarray, w: jnp.ndarray, group_ids: jnp.ndarray,
         w: per-group weights, [E, K, N].
         group_ids: group index per ``bm``-row block, [T / bm] int32.
         bm, bk, bn: MXU tile sizes (rows, contraction, columns).
-        interpret: force Pallas interpret mode; default: off-TPU only.
+        interpret: force Pallas interpret mode; default: CPU backend only.
 
     Returns:
         ``Y[i] = x[i] @ w[group_ids[i // bm]]`` as a dense [T, N] array.
     """
     _warn_deprecated("grouped_matmul")
     return grouped_matmul_pallas(x, w, group_ids, bm=bm, bk=bk, bn=bn,
+                                 vmem_limit=_vmem_limit(),
                                  interpret=_interpret(interpret))

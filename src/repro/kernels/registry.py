@@ -13,7 +13,9 @@ kernel layer uniform: each ``(format, backend)`` pair registers a
   * ``estimate(m, d, ctx)`` — the sparsity-aware roofline placement of a
     launch (AI, useful vs issued FLOPs, attainable GFLOP/s);
   * ``vmem_footprint(n, d, ctx)`` — the kernel's modeled resident VMEM
-    working set in bytes (0 for XLA-managed jax backends).
+    working set in bytes (0 for XLA-managed jax backends);
+  * ``smem_footprint(m, ctx)`` — the scalar-prefetched metadata it keeps
+    in SMEM for matrix ``m`` (0 where it prefetches nothing).
 
 ``repro.sparse.dispatch.Dispatcher.executor`` resolves the winning plan
 through :func:`get`; ``repro.sparse.stream`` replays the bound closure;
@@ -22,10 +24,12 @@ through :func:`get`; ``repro.sparse.stream`` replays the bound closure;
 spec to fit measured compute ceilings.  :func:`spmm` is the one-call
 registry entry point for direct use.
 
-The CSR Pallas spec is where the VMEM model matters: ``prepare`` picks the
-B row-slab size from ``ctx.hardware.vmem_bytes`` (``choose_b_tile``), so
-the kernel streams B slab-by-slab and stays eligible at any ``n`` instead
-of capping out at ``n * bd * 4 <= VMEM``.
+Every Pallas kernel is launched with the same scoped VMEM limit,
+``KernelContext.vmem_limit`` (``hardware.kernel_vmem_limit``), and every
+resident block is sized from it counting double-buffering: the binned
+spec's B row slab comes from ``choose_b_tile`` on that budget, and the
+dispatcher skips a Pallas candidate whose ``vmem_footprint`` exceeds it.
+The CSR kernel gathers rows of B straight from HBM and holds no slab.
 """
 from __future__ import annotations
 
@@ -38,8 +42,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import sparsity_models as sm
-from repro.core.hardware import HOST_CPU, TPU_V5E, HardwareSpec
-from repro.core.precision import DEFAULT_PRECISION, Precision
+from repro.core.hardware import (
+    TPU_V5E, HardwareSpec, device_hardware, kernel_vmem_limit)
+from repro.core.precision import (
+    DEFAULT_PRECISION, INT16_MAX_EXTENT, Precision)
 from repro.kernels.banded_spmm import banded_spmm_pallas
 from repro.kernels.bcsr_spmm import bcsr_spmm_pallas
 from repro.kernels.binned_spmm import (
@@ -59,12 +65,21 @@ BACKENDS: Tuple[str, ...] = ("jax", "pallas")
 #: 2 = per-d B-slab re-packing (``KernelContext.plan_d``),
 #: 3 = scale-free kernel tier (binned / rowsplit / ell_coo),
 #: 4 = precision axis (bf16 values / int16 indices; dtype-sized slabs
-#: and footprints).
-REGISTRY_VERSION: int = 4
+#: and footprints), 5 = chip bring-up (CSR gathers from HBM, int8 row
+#: slots, 32-row tiles, slabs sized from the scoped VMEM limit).
+REGISTRY_VERSION: int = 5
+
+#: Rows per output tile of the CSR-family kernels (and per binned visit).
+ROW_TILE: int = 32
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def default_interpret() -> bool:
+    """Whether Pallas kernels run in interpret mode when nobody says.
+
+    Only on the CPU backend (the tests): on an accelerator the kernels
+    are compiled, and a kernel the chip refuses is an error.
+    """
+    return jax.default_backend() == "cpu"
 
 
 def pallas_block_d(d: int) -> int:
@@ -83,30 +98,30 @@ def pallas_band_tile(n: int) -> int:
     return 1
 
 
-def choose_b_tile(n: int, vmem_bytes: int, *, bd: int = 512,
-                  sizeof_val: int = 4) -> Optional[int]:
-    """B row-slab size for the streamed CSR kernel, from the VMEM budget.
+def choose_b_tile(n: int, vmem_budget: int, *,
+                  bd: int = 512) -> Optional[int]:
+    """B row-slab size for the binned kernel, from the VMEM budget.
 
-    Half the VMEM goes to the resident B slab (the rest covers the C tile,
-    index chunks, gather scratch, and double buffering).  Returns ``None``
-    when all of B fits — the layout then reduces to the unstreamed
-    original (one slab, global column ids).
+    ``vmem_budget`` is the kernel's scoped VMEM limit.  Half of it goes to
+    the resident B slab, which the pipeline double-buffers, so one slab
+    takes a quarter; the rest covers the partial-C block, index chunks
+    and the gather scratch.  Gathered rows are fp32 whatever the storage
+    precision (see ``repro.kernels.csr_spmm``).  Slabs stop at
+    ``INT16_MAX_EXTENT`` rows so slab-local columns stay int16-legal.
+    Returns ``None`` when all of B fits — the layout then reduces to one
+    slab with global column ids.
 
     ``bd`` is the kernel's d-tile width the slab must host.  The default
-    512 is the widest tile — safe for any ``d`` but, when the planned
-    width is far below it, it undersizes the slab by the ratio
-    ``512 / bd`` (the budget is charged for columns that never
-    materialize).  Callers that know ``d`` at plan time pass the actual
-    tile (``KernelContext.plan_d`` routes this through
-    ``resolve_b_tile``), so small-d plans get proportionally taller
-    slabs and fewer slab passes.
+    512 is the widest tile; callers that know ``d`` at plan time pass the
+    actual tile (``KernelContext.plan_d`` routes this through
+    ``resolve_b_tile``), so small-d plans get taller slabs.
     """
-    if vmem_bytes <= 0:
+    if vmem_budget <= 0:
         return None
-    slab_rows = (vmem_bytes // 2) // (bd * sizeof_val)
+    slab_rows = vmem_budget // 4 // (bd * 4)
     if slab_rows >= n:
         return None
-    return max(8, int(slab_rows) // 8 * 8)
+    return max(8, min(int(slab_rows), INT16_MAX_EXTENT) // 8 * 8)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -114,33 +129,36 @@ class KernelContext:
     """Knobs a :class:`KernelSpec` needs to prepare and launch.
 
     Attributes:
-        hardware: ceilings of the target device; ``vmem_bytes`` drives the
-            streamed-CSR slab size and the footprint models.
+        hardware: ceilings of the target device (default: the spec of
+            JAX's default device, ``hardware.device_hardware``); its
+            ``vmem_bytes`` sets the kernels' scoped VMEM limit.
         bcsr_block: BCSR block edge t.
         max_dia_offsets: DIA conversion cap (mirrors the dispatch policy).
-        interpret: force Pallas interpret mode; None = off-TPU only.
-        row_tile: CSR kernel rows per C tile.
-        chunk: CSR kernel nonzeros per packed chunk.
-        b_tile: explicit B row-slab override for the streamed CSR kernel;
-            None picks it from ``hardware.vmem_bytes`` (``choose_b_tile``).
+        interpret: force Pallas interpret mode; None = on the CPU backend
+            only (``default_interpret``).
+        row_tile: CSR-family kernel rows per C tile (at most 128).
+        chunk: CSR-family kernel nonzeros per packed chunk.
+        b_tile: explicit B row-slab override for the binned kernel; None
+            picks it from the VMEM limit (``choose_b_tile``).
         plan_d: the dense width the plan was made for, when known; lets
             ``resolve_b_tile`` size the B slab for the actual d-tile
             instead of the worst-case 512 (per-d slab re-packing).  None
             keeps the conservative sizing.
         precision: value/index storage dtypes the layouts are packed at
-            (``repro.core.precision.Precision``); sizes the VMEM slab
-            budget and footprints by the actual element widths.
+            (``repro.core.precision.Precision``); sizes the footprints by
+            the actual element widths.
         convert: optional ``(m, format) -> container`` hook so prepare
             reuses the caller's conversion cache (the dispatcher passes
             its own ``convert`` method, bound to this precision); None
             converts directly at ``precision``'s value dtype.
     """
 
-    hardware: HardwareSpec = HOST_CPU
+    hardware: HardwareSpec = dataclasses.field(
+        default_factory=device_hardware)
     bcsr_block: int = 64
     max_dia_offsets: int = 64
     interpret: Optional[bool] = None
-    row_tile: int = 8
+    row_tile: int = ROW_TILE
     chunk: int = 128
     b_tile: Optional[int] = None
     plan_d: Optional[int] = None
@@ -148,21 +166,22 @@ class KernelContext:
     convert: Optional[Callable[[Any, str], Any]] = None
 
     def resolve_interpret(self) -> bool:
-        """Pallas interpret flag: forced value, else off-TPU only."""
-        return (not _on_tpu()) if self.interpret is None else self.interpret
+        """Pallas interpret flag: forced value, else CPU backend only."""
+        return default_interpret() if self.interpret is None \
+            else self.interpret
+
+    @property
+    def vmem_limit(self) -> int:
+        """Scoped VMEM every Pallas launch requests, in bytes."""
+        return kernel_vmem_limit(self.hardware)
 
     def resolve_b_tile(self, n: int) -> Optional[int]:
-        """The streamed-CSR slab size for an ``[n, n]`` matrix.
-
-        The slab budget is charged at the operand's actual element size,
-        so bf16 streams get 2x taller slabs than fp32 for the same VMEM.
-        """
+        """The binned kernel's B slab height for an ``[n, n]`` matrix."""
         if self.b_tile is not None:
             return self.b_tile if self.b_tile < n else None
         bd = 512 if self.plan_d is None else min(512,
                                                  pallas_block_d(self.plan_d))
-        return choose_b_tile(n, self.hardware.vmem_bytes, bd=bd,
-                             sizeof_val=self.precision.sizeof_val)
+        return choose_b_tile(n, self.vmem_limit, bd=bd)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -215,6 +234,10 @@ class KernelSpec:
     #: of a *particular* matrix is still checked at prepare time — an
     #: extent past ``2**15 - 1`` raises ``ValueError``).
     supported_precisions: Tuple[str, ...] = ("f32i32",)
+    #: Bytes of scalar-prefetched metadata (per-tile chunk ranges, block
+    #: coordinates) the kernel holds in SMEM for matrix ``m``.
+    smem_footprint: Callable[[Any, KernelContext], int] = \
+        lambda m, ctx: 0
 
     def supports_precision(self, precision: Precision) -> bool:
         """True iff this kernel can execute at ``precision``."""
@@ -338,15 +361,14 @@ def band_to_blocks(dia_data: np.ndarray, offsets, *, n: int, t: int):
     nb = (n + t - 1) // t
     max_off = max(abs(int(o)) for o in offsets) if len(offsets) else 0
     w = (max_off + t - 1) // t
-    band = np.zeros((nb, 2 * w + 1, t, t), dtype=np.asarray(dia_data).dtype)
     dia = np.asarray(dia_data)
+    band = np.zeros((nb, 2 * w + 1, t, t), dtype=dia.dtype)
+    rows = np.arange(n, dtype=np.int64)
     for oi, off in enumerate(offsets):
-        off = int(off)
-        for r in range(n):
-            c = r + off
-            if 0 <= c < n and dia[oi, r] != 0:
-                bi, bj = r // t, c // t
-                band[bi, bj - bi + w, r % t, c % t] = dia[oi, r]
+        c = rows + int(off)
+        keep = (c >= 0) & (c < n) & (dia[oi, :n] != 0)
+        r, c = rows[keep], c[keep]
+        band[r // t, c // t - r // t + w, r % t, c % t] = dia[oi, :n][keep]
     return jnp.asarray(band), w
 
 
@@ -455,7 +477,7 @@ def _convert(ctx: KernelContext, m, format: str):
 # ------------------------------------------------------------------ #
 
 def binned_layout_stats(m, *, slab_rows: int,
-                        row_tile: int = 8) -> Tuple[int, int]:
+                        row_tile: int = ROW_TILE) -> Tuple[int, int]:
     """(slabs_touched, num_visits) of the slab-binned layout for ``m``.
 
     A visit is one (B slab, row tile) pair with nonzeros — the unit the
@@ -470,6 +492,23 @@ def binned_layout_stats(m, *, slab_rows: int,
     num_slabs = max(1, -(-m.n // slab_rows))
     visits = np.unique(tiles * num_slabs + slabs).shape[0]
     return int(np.unique(slabs).shape[0]), int(visits)
+
+
+def binned_padded_slots(m, *, slab_rows: int, row_tile: int = ROW_TILE,
+                        chunk: int = 128) -> int:
+    """Slots of the packed binned layout: each visit padded to chunks.
+
+    The packing-inflation gate compares this against ``m.nnz``: where the
+    nonzeros of a row tile scatter over many slabs, every visit holds a
+    few nonzeros and still pays a whole chunk.
+    """
+    if m.nnz == 0:
+        return chunk
+    slabs = np.asarray(m.cols, dtype=np.int64) // slab_rows
+    tiles = np.asarray(m.rows, dtype=np.int64) // row_tile
+    num_slabs = max(1, -(-m.n // slab_rows))
+    _, counts = np.unique(tiles * num_slabs + slabs, return_counts=True)
+    return int((-(-counts // chunk)).sum()) * chunk
 
 
 def rowsplit_window_model(n_nonempty: int, nnz: int,
@@ -621,26 +660,21 @@ for _f, _desc, _est in (
 
 def _csr_pallas_prepare(m, ctx: KernelContext):
     csr = _convert(ctx, m, "csr")
-    bt = ctx.resolve_b_tile(m.n)
-    tiles, slabs, cols, slots, vals = csr_to_row_tiles(
+    arrays = csr_to_row_tiles(
         np.asarray(csr.indptr), np.asarray(csr.indices),
         np.asarray(csr.data), n=csr.n, row_tile=ctx.row_tile,
-        chunk=ctx.chunk, b_tile=bt,
-        index_dtype=ctx.precision.index_np)
-    return {"n": csr.n, "b_tile": bt, "row_tile": ctx.row_tile,
-            "arrays": tuple(jnp.asarray(x)
-                            for x in (tiles, slabs, cols, slots, vals))}
+        chunk=ctx.chunk, index_dtype=ctx.precision.index_np)
+    return {"n": csr.n, "row_tile": ctx.row_tile,
+            "arrays": tuple(jnp.asarray(x) for x in arrays)}
 
 
 def _csr_pallas_run(layout, b, ctx: KernelContext):
-    tiles, slabs, cols, slots, vals = layout["arrays"]
     if ctx.precision.reduced:
         b = b.astype(ctx.precision.value_jnp)
     return csr_spmm_pallas(
-        tiles, slabs, cols, slots, vals, b, n=layout["n"],
-        row_tile=layout["row_tile"], b_tile=layout["b_tile"],
-        block_d=pallas_block_d(b.shape[1]),
-        interpret=ctx.resolve_interpret())
+        *layout["arrays"], b, n=layout["n"],
+        row_tile=layout["row_tile"], block_d=pallas_block_d(b.shape[1]),
+        vmem_limit=ctx.vmem_limit, interpret=ctx.resolve_interpret())
 
 
 def _csr_pallas_estimate(m, d, ctx: KernelContext) -> KernelRoofline:
@@ -651,16 +685,26 @@ def _csr_pallas_estimate(m, d, ctx: KernelContext) -> KernelRoofline:
         mxu_utilization=1.0)
 
 
+def _chunk_footprint(rows: int, bd: int, ctx: KernelContext) -> int:
+    """VMEM of the CSR-family chunk machinery, double-buffered blocks.
+
+    The fp32 ``[chunk, bd]`` gather scratch, two buffers each of the
+    value, column and int8 slot chunks, and of the fp32 ``[rows, bd]``
+    output block.
+    """
+    p = ctx.precision
+    return (4 * ctx.chunk * bd
+            + 2 * ctx.chunk * (p.sizeof_val + p.sizeof_idx + 1)
+            + 2 * 4 * rows * bd)
+
+
 def _csr_pallas_footprint(n: int, d: int, ctx: KernelContext) -> int:
-    bd = min(512, pallas_block_d(d))
-    bt = ctx.resolve_b_tile(n) or n
-    sv = ctx.precision.sizeof_val
-    si = ctx.precision.sizeof_idx
-    # Resident: B slab + gathered chunk + vals chunk at the value width,
-    # cols/slots chunks at the index width, C tile always fp32 (the VMEM
-    # accumulator keeps full precision regardless of operand dtype).
-    return (sv * (bt * bd + ctx.chunk * bd + ctx.chunk)
-            + si * 2 * ctx.chunk + 4 * ctx.row_tile * bd)
+    # B stays in HBM: nothing in the working set grows with n.
+    return _chunk_footprint(ctx.row_tile, min(512, pallas_block_d(d)), ctx)
+
+
+def _csr_pallas_smem(m, ctx: KernelContext) -> int:
+    return 4 * (-(-m.n // ctx.row_tile) + 1)        # per-tile chunk starts
 
 
 for _f in ("csr", "ell"):
@@ -669,10 +713,11 @@ for _f in ("csr", "ell"):
     # both specs share one cached row-tile packing per matrix).
     register(KernelSpec(
         format=_f, backend="pallas",
-        description="row-tiled gather/segment-sum kernel, B streamed by "
-                    "VMEM-sized row slabs",
+        description="row-tiled gather/segment-sum kernel, B rows DMA'd "
+                    "from HBM",
         prepare=_csr_pallas_prepare, run=_csr_pallas_run,
         estimate=_csr_pallas_estimate, vmem_footprint=_csr_pallas_footprint,
+        smem_footprint=_csr_pallas_smem,
         layout_key="csr", supported_precisions=_PALLAS_STREAM_PRECISIONS))
 
 
@@ -689,14 +734,27 @@ def _binned_pallas_prepare(m, ctx: KernelContext):
 
 
 def _binned_pallas_run(layout, b, ctx: KernelContext):
-    vt, cv, cs, cols, slots, vals = layout["arrays"]
     if ctx.precision.reduced:
         b = b.astype(ctx.precision.value_jnp)
     return binned_spmm_pallas(
-        vt, cv, cs, cols, slots, vals, b, n=layout["n"],
+        *layout["arrays"], b, n=layout["n"],
         row_tile=layout["row_tile"], b_tile=layout["b_tile"],
-        block_d=pallas_block_d(b.shape[1]),
+        block_d=pallas_block_d(b.shape[1]), vmem_limit=ctx.vmem_limit,
         interpret=ctx.resolve_interpret())
+
+
+def _binned_pallas_footprint(n: int, d: int, ctx: KernelContext) -> int:
+    bd = min(512, pallas_block_d(d))
+    bt = ctx.resolve_b_tile(n) or -(-n // 8) * 8
+    # One fp32 B slab, double-buffered, on top of the chunk machinery
+    # (the visit partials live in HBM and stream through the C block).
+    return 2 * 4 * bt * bd + _chunk_footprint(ctx.row_tile, bd, ctx)
+
+
+def _binned_pallas_smem(m, ctx: KernelContext) -> int:
+    _, visits = binned_layout_stats(m, slab_rows=ctx.resolve_b_tile(m.n)
+                                    or m.n, row_tile=ctx.row_tile)
+    return 4 * (2 * visits + 1)               # visit slabs + chunk starts
 
 
 register(KernelSpec(
@@ -705,10 +763,8 @@ register(KernelSpec(
                 "VMEM-resident B slabs, segment-sum epilogue",
     prepare=_binned_pallas_prepare, run=_binned_pallas_run,
     estimate=_binned_estimate("binned_spmm", _pallas_slab),
-    # Residency matches the streamed CSR kernel: one B slab, one partial
-    # C block, and the gather/index chunks (the visit partials live in
-    # HBM and stream through the same C-tile slot).
-    vmem_footprint=_csr_pallas_footprint,
+    vmem_footprint=_binned_pallas_footprint,
+    smem_footprint=_binned_pallas_smem,
     layout_key="binned", supported_precisions=_PALLAS_STREAM_PRECISIONS))
 
 
@@ -730,19 +786,16 @@ def _rowsplit_pallas_run(layout, b, ctx: KernelContext):
     return rowsplit_spmm_pallas(
         row_map, cols, slots, vals, b, n=layout["n"],
         window=layout["window"], block_d=pallas_block_d(b.shape[1]),
-        interpret=ctx.resolve_interpret())
+        vmem_limit=ctx.vmem_limit, interpret=ctx.resolve_interpret())
 
 
 def _rowsplit_pallas_footprint(n: int, d: int, ctx: KernelContext) -> int:
     bd = min(512, pallas_block_d(d))
     n_pad = -(-n // 8) * 8
-    sv = ctx.precision.sizeof_val
-    si = ctx.precision.sizeof_idx
-    # Whole B resident (the load-balance kernel does not stream B) plus
-    # the gather chunk and vals at the value width, cols/slots at the
-    # index width, and the fp32 window partial.
-    return (sv * (n_pad * bd + ctx.chunk * bd + ctx.chunk)
-            + si * 2 * ctx.chunk + 4 * ctx.chunk * bd)
+    # Whole fp32 B resident and double-buffered (the load-balance kernel
+    # does not stream B), plus the chunk machinery with the widest
+    # possible window (one row per nonzero of a chunk).
+    return 2 * 4 * n_pad * bd + _chunk_footprint(ctx.chunk, bd, ctx)
 
 
 register(KernelSpec(
@@ -765,7 +818,7 @@ register(KernelSpec(
     description="hybrid ELL/COO pick lowered to the row-tiled CSR kernel",
     prepare=_csr_pallas_prepare, run=_csr_pallas_run,
     estimate=_ell_coo_estimate("ell_coo_spmm"),
-    vmem_footprint=_csr_pallas_footprint,
+    vmem_footprint=_csr_pallas_footprint, smem_footprint=_csr_pallas_smem,
     layout_key="csr", supported_precisions=_PALLAS_STREAM_PRECISIONS))
 
 
@@ -779,7 +832,7 @@ def _bcsr_pallas_run(layout, b, ctx: KernelContext):
     return bcsr_spmm_pallas(
         layout.blocks, layout.block_rows, layout.block_cols, b,
         n=layout.n, t=layout.t, block_d=pallas_block_d(b.shape[1]),
-        interpret=ctx.resolve_interpret())
+        vmem_limit=ctx.vmem_limit, interpret=ctx.resolve_interpret())
 
 
 def _bcsr_estimate(m, d, ctx: KernelContext) -> KernelRoofline:
@@ -795,11 +848,21 @@ def _bcsr_estimate(m, d, ctx: KernelContext) -> KernelRoofline:
         mxu_utilization=sm.mxu_utilization(m.nnz, t, N))
 
 
+def _block_footprint(t: int, bd: int, ctx: KernelContext) -> int:
+    """A ``t x t`` block and a ``t x bd`` B tile at the value width plus
+    the fp32 ``t x bd`` C tile, each double-buffered."""
+    return 2 * (ctx.precision.sizeof_val * (t * t + t * bd) + 4 * t * bd)
+
+
 def _bcsr_pallas_footprint(n: int, d: int, ctx: KernelContext) -> int:
-    t, bd = ctx.bcsr_block, min(512, pallas_block_d(d))
-    # Block + B tile at the value width; the C tile accumulates in fp32.
-    sv = ctx.precision.sizeof_val
-    return sv * (t * t + t * bd) + 4 * t * bd
+    return _block_footprint(ctx.bcsr_block, min(512, pallas_block_d(d)), ctx)
+
+
+def _bcsr_pallas_smem(m, ctx: KernelContext) -> int:
+    from repro.core.classify import block_stats
+    blocks = int(block_stats(m, ctx.bcsr_block)["N"])
+    # Block rows and columns, plus one zero block per empty block row.
+    return 8 * (blocks + -(-m.n // ctx.bcsr_block))
 
 
 register(KernelSpec(
@@ -807,6 +870,7 @@ register(KernelSpec(
     description="dense-block MXU kernel (scalar-prefetch block walk)",
     prepare=_bcsr_pallas_prepare, run=_bcsr_pallas_run,
     estimate=_bcsr_estimate, vmem_footprint=_bcsr_pallas_footprint,
+    smem_footprint=_bcsr_pallas_smem,
     # Block coordinates are scalar-prefetch metadata, not per-nonzero
     # traffic, so bcsr gains nothing from int16 and keeps int32.
     supported_precisions=_JAX_PRECISIONS))
@@ -824,7 +888,7 @@ def _dia_pallas_run(layout, b, ctx: KernelContext):
         b = b.astype(ctx.precision.value_jnp)
     return banded_spmm_pallas(
         layout["band"], b, t=layout["t"], w=layout["w"],
-        block_d=pallas_block_d(b.shape[1]),
+        block_d=pallas_block_d(b.shape[1]), vmem_limit=ctx.vmem_limit,
         interpret=ctx.resolve_interpret())
 
 
@@ -833,9 +897,8 @@ def _dia_pallas_estimate(m, d, ctx: KernelContext) -> KernelRoofline:
 
 
 def _dia_pallas_footprint(n: int, d: int, ctx: KernelContext) -> int:
-    t, bd = pallas_band_tile(n), min(512, pallas_block_d(d))
-    sv = ctx.precision.sizeof_val
-    return sv * (t * t + t * bd) + 4 * t * bd
+    return _block_footprint(pallas_band_tile(n), min(512, pallas_block_d(d)),
+                            ctx)
 
 
 register(KernelSpec(
@@ -856,6 +919,7 @@ def _grouped_prepare(operand, ctx: KernelContext):
 def _grouped_run(layout, x, ctx: KernelContext):
     w, group_ids, bm, bk, bn = layout
     return grouped_matmul_pallas(x, w, group_ids, bm=bm, bk=bk, bn=bn,
+                                 vmem_limit=ctx.vmem_limit,
                                  interpret=ctx.resolve_interpret())
 
 
@@ -868,7 +932,7 @@ def _grouped_estimate(operand, d, ctx: KernelContext) -> KernelRoofline:
 
 def _grouped_footprint(n: int, d: int, ctx: KernelContext) -> int:
     bm = bk = bn = 128
-    return 4 * (bm * bk + bk * bn + bm * bn)
+    return 2 * 4 * (bm * bk + bk * bn + bm * bn)
 
 
 register(KernelSpec(

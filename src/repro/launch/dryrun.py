@@ -4,6 +4,11 @@ MUST be executed as a fresh process (``python -m repro.launch.dryrun``): the
 first two lines force 512 host platform devices before jax initializes.
 Smoke tests and benchmarks run in normal processes and see 1 device.
 
+A CPU tool, never on the chip path: it fakes 512 host devices and
+``--all`` starts one child process per cell after the parent has touched
+JAX, and a chip belongs to one process at a time.  Run it with
+``JAX_PLATFORMS=cpu``; ``chip_smoke.py`` is what runs on the chip.
+
 Per cell this:
   1. builds the production mesh (16x16 or 2x16x16),
   2. lowers the train/prefill/serve step with abstract ShapeDtypeStruct

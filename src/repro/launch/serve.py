@@ -110,7 +110,7 @@ def run_startup_calibration() -> None:
 
     disp = sparse.default_dispatcher()
     backend = disp._resolve_backend()
-    hw = disp._resolve_hardware(backend)
+    hw = disp._resolve_hardware()
     t0 = time.perf_counter()
     store = CalibrationStore()
     cal = calibrate(hw, backend=backend, store=store)
@@ -308,6 +308,8 @@ def serve_spmm_engine(args) -> None:
 
 def main():
     """Parse arguments and run either the LM or the streamed-SpMM server."""
+    from repro.launch.compile_cache import configure
+    configure()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch")
     ap.add_argument("--reduced", action="store_true")

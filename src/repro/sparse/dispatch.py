@@ -50,7 +50,8 @@ from repro.core.precision import (DEFAULT_PRECISION, INT16_MAX_EXTENT,
                                   PRECISIONS, Precision, as_precision)
 from repro.data.dtree import (DecisionTree, DispatchTreeStore,
                               features_from_report)
-from repro.core.hardware import HOST_CPU, TPU_V5E, HardwareSpec
+from repro.core.hardware import (
+    HardwareSpec, device_hardware, kernel_smem_limit)
 from repro.core.roofline import ComputeCeiling
 from repro.core import sparsity_models as sm
 from repro.core.patterns import COOMatrix
@@ -91,6 +92,11 @@ DEFAULT_EFFICIENCY: Dict[str, Tuple[float, float]] = {
     # and beat BCSR on FEM suites it measures 2x slower on.)
     "ell_coo": (0.036, 40.0),
 }
+
+#: Largest packed-slot count per nonzero a Pallas binned layout may have.
+#: Each (slab, row tile) visit is padded to whole chunks; past this the
+#: layout (and the HBM it takes) grows with the padding, not with nnz.
+MAX_PACKED_INFLATION: float = 1.5
 
 
 @dataclasses.dataclass(frozen=True)
@@ -468,11 +474,45 @@ class Dispatcher:
             return self.backend
         return "pallas" if jax.default_backend() == "tpu" else "jax"
 
-    def _resolve_hardware(self, backend: str) -> HardwareSpec:
+    def _resolve_hardware(self) -> HardwareSpec:
+        """The spec planning runs against: the constructor's, else the
+        default device's (``repro.core.hardware.device_hardware``)."""
         if self.hardware is not None:
             return self.hardware
-        return TPU_V5E if backend == "pallas" and \
-            jax.default_backend() == "tpu" else HOST_CPU
+        return device_hardware()
+
+    def _pallas_gate(self, m: COOMatrix, format: str, d: int,
+                     hw: HardwareSpec) -> Optional[str]:
+        """Why the Pallas kernel for ``format`` cannot run ``m`` on ``hw``.
+
+        Device limits, recorded like any policy skip: a modelled VMEM
+        working set over the kernel's scoped VMEM limit (the row-split
+        kernel holds all of B), scalar-prefetched metadata over the SMEM
+        budget, and a binned packing whose per-visit chunk padding
+        exceeds ``MAX_PACKED_INFLATION``.  None when all hold.
+        """
+        from repro.kernels import registry as kreg
+        ctx = kreg.KernelContext(hardware=hw, bcsr_block=self.bcsr_block,
+                                 plan_d=d)
+        spec = kreg.get(format, "pallas")
+        footprint = spec.vmem_footprint(m.n, d, ctx)
+        if footprint > ctx.vmem_limit:
+            return (f"VMEM footprint {footprint / 2 ** 20:.1f} MiB exceeds "
+                    f"the {ctx.vmem_limit / 2 ** 20:.0f} MiB kernel budget")
+        smem, smem_limit = spec.smem_footprint(m, ctx), kernel_smem_limit(hw)
+        if smem_limit and smem > smem_limit:
+            return (f"scalar-prefetched metadata {smem / 2 ** 10:.0f} KiB "
+                    f"exceeds the {smem_limit / 2 ** 10:.0f} KiB SMEM "
+                    f"budget")
+        if format == "binned" and m.nnz:
+            slots = kreg.binned_padded_slots(
+                m, slab_rows=ctx.resolve_b_tile(m.n) or m.n,
+                row_tile=ctx.row_tile, chunk=ctx.chunk)
+            if slots > MAX_PACKED_INFLATION * m.nnz:
+                return (f"binned packing pads its slab visits to "
+                        f"{slots / m.nnz:.1f}x the nonzeros (limit "
+                        f"{MAX_PACKED_INFLATION}x)")
+        return None
 
     def _policy(self, m: COOMatrix, report: StructureReport,
                 format: str) -> Tuple[bool, Optional[str], dict]:
@@ -522,10 +562,10 @@ class Dispatcher:
                     f"matrices"), params
             return True, None, params
         if format in ("binned", "rowsplit"):
-            # Both degrade gracefully on any structure (binned collapses
-            # to CSR order when one slab covers the matrix; rowsplit's
-            # padding is bounded by one chunk), so they are always
-            # eligible — the roofline model, not a gate, decides.
+            # Structurally both run anything (binned collapses to CSR
+            # order when one slab covers the matrix; rowsplit's padding
+            # is bounded by one chunk); their Pallas kernels' device
+            # limits are checked by ``_pallas_gate``.
             return True, None, {}
         if format == "ell_coo":
             deg = np.bincount(m.rows, minlength=m.n)
@@ -587,12 +627,11 @@ class Dispatcher:
             # from the Eq. 2 worst case.  (Lazy import: repro.kernels
             # imports this package for its format containers.)
             from repro.kernels import registry as kreg
-            slab = kreg.choose_b_tile(
-                n, hw.vmem_bytes, bd=min(512, kreg.pallas_block_d(d)),
-                sizeof_val=sv) or n
+            slab = self._binned_slab(n, d, hw, backend)
             touched, visits = kreg.binned_layout_stats(m, slab_rows=slab)
             tb = sm.ai_binned(n, nnz, d, slab_rows=slab,
                               slabs_touched=touched, num_visits=visits,
+                              row_tile=kreg.ROW_TILE,
                               sizeof_val=sv, sizeof_idx=si)
             bytes_a, bytes_b, bytes_c = tb.bytes_a, tb.bytes_b, tb.bytes_c
             useful = 1.0
@@ -642,26 +681,32 @@ class Dispatcher:
         return (ai, useful, predicted / 1e9, amortized / 1e9, conv,
                 ceiling.source)
 
+    @staticmethod
+    def _binned_slab(n: int, d: int, hw: HardwareSpec, backend: str) -> int:
+        """B slab height of the binned kernel ``backend`` runs: the same
+        functions the kernels' layouts are packed with."""
+        if backend == "pallas":
+            from repro.kernels import registry as kreg
+            return kreg.KernelContext(hardware=hw,
+                                      plan_d=d).resolve_b_tile(n) or n
+        return fmt.default_slab_rows(n)
+
     def _index_extent(self, m: COOMatrix, format: str, d: int,
-                      hw: HardwareSpec, prec: Precision) -> int:
+                      hw: HardwareSpec, backend: str) -> int:
         """The largest extent a packed index of this layout addresses.
 
-        Slab-streamed Pallas layouts (csr / ell / binned / ell_coo)
-        store slab-local column ids, so the extent is the B row-slab
-        size (the whole matrix when B fits unstreamed); the rowsplit
-        packing keeps *global* column ids, so its extent is always n.
-        Matches the packers' own ``index_extent_check`` at prepare time.
+        The binned layout stores slab-local column ids, so its extent is
+        the B row-slab height; every other layout keeps global column
+        ids, so the extent is n.  Matches the packers' own
+        ``index_extent_check`` at prepare time.
         """
-        if format == "rowsplit":
-            return m.n
-        from repro.kernels import registry as kreg
-        bt = kreg.choose_b_tile(
-            m.n, hw.vmem_bytes, bd=min(512, kreg.pallas_block_d(d)),
-            sizeof_val=prec.sizeof_val)
-        return m.n if bt is None else bt
+        if format == "binned":
+            return self._binned_slab(m.n, d, hw, backend)
+        return m.n
 
     def _precision_gate(self, m: COOMatrix, format: str, prec: Precision,
-                        d: int, hw: HardwareSpec, tolerance: float,
+                        d: int, hw: HardwareSpec, backend: str,
+                        tolerance: float,
                         forced: bool) -> Tuple[bool, Optional[str]]:
         """Accuracy/legality gate for one (format, precision) row.
 
@@ -671,7 +716,7 @@ class Dispatcher:
         a forced strategy bypasses the auto ranking (but not policy).
         """
         if prec.index_dtype == "int16":
-            extent = self._index_extent(m, format, d, hw, prec)
+            extent = self._index_extent(m, format, d, hw, backend)
             if not fmt.int16_extent_ok(extent):
                 return False, (
                     f"int16 indices cannot address extent {extent} "
@@ -743,7 +788,7 @@ class Dispatcher:
         forced_tok = None if precision is None \
             else as_precision(precision).token
         backend = self._resolve_backend()
-        hw = self._resolve_hardware(backend)
+        hw = self._resolve_hardware()
         # The fitted tree is part of the plan identity: refitting (or
         # deleting) the persisted tree must not replay stale decisions.
         tree = self._tree(backend) if strategy == "auto" else None
@@ -758,12 +803,15 @@ class Dispatcher:
         cands = []
         for f in FORMATS:
             eligible, reason, params = self._policy(m, report, f)
+            if eligible and backend == "pallas":
+                reason = self._pallas_gate(m, f, d, hw)
+                eligible = reason is None
             spec_tokens = kreg.get(f, backend).supported_precisions
             for prec in PRECISIONS:
                 if prec.token not in spec_tokens:
                     continue
                 p_ok, p_reason = self._precision_gate(
-                    m, f, prec, d, hw, tolerance,
+                    m, f, prec, d, hw, backend, tolerance,
                     forced=prec.token == forced_tok)
                 source = "default"
                 row_params = dict(params)
@@ -902,12 +950,15 @@ class Dispatcher:
             (any ``d`` — the kernel tile width adapts per call), ``c`` is
             ``[n, d]``.
         """
-        # Uniform path: resolve the KernelSpec for (format, backend) and
-        # cache its prepared layout per matrix — per-call packing would
-        # dominate the kernel.  (Lazy import: repro.kernels imports this
-        # package for its format containers.)
         from repro.kernels import registry
         spec = registry.get(plan.chosen, plan.backend)
+        ctx = self._kernel_context(plan)
+        layout = self.layout(m, plan)
+        return lambda b: spec.run(layout, b, ctx)
+
+    def _kernel_context(self, plan: DispatchPlan):
+        """The KernelContext ``plan``'s kernel is prepared and run with."""
+        from repro.kernels import registry
         prec = as_precision(plan.precision)
 
         def _convert(mm, format, _prec=prec):
@@ -915,13 +966,25 @@ class Dispatcher:
             # precision (the registry's hook is two-argument).
             return self.convert(mm, format, precision=_prec)
 
-        ctx = registry.KernelContext(
-            hardware=self._resolve_hardware(plan.backend),
+        return registry.KernelContext(
+            hardware=self._resolve_hardware(),
             bcsr_block=self.bcsr_block,
             max_dia_offsets=self.max_dia_offsets,
             plan_d=plan.d,          # per-d B-slab re-packing
-            precision=prec,         # dtype-sized slabs, packed indices
+            precision=prec,         # dtype-sized footprints, packed indices
             convert=_convert)
+
+    def layout(self, m: COOMatrix, plan: DispatchPlan):
+        """The prepared kernel layout ``plan`` replays for ``m`` (cached).
+
+        Uniform path: the KernelSpec for (format, backend) prepares it once
+        per matrix — per-call packing would dominate the kernel.
+        """
+        # Lazy import: repro.kernels imports this package for its format
+        # containers.
+        from repro.kernels import registry
+        spec = registry.get(plan.chosen, plan.backend)
+        prec = as_precision(plan.precision)
         # The resolved d-tile and the storage precision are part of the
         # layout identity: two plans whose widths map to different slab
         # sizings, or whose layouts pack different dtypes, must not
@@ -930,9 +993,9 @@ class Dispatcher:
               self.bcsr_block, registry.pallas_block_d(plan.d),
               prec.token)
         if ck not in self._converted:
-            self._converted[ck] = spec.prepare(m, ctx)
-        layout = self._converted[ck]
-        return lambda b: spec.run(layout, b, ctx)
+            self._converted[ck] = spec.prepare(m,
+                                               self._kernel_context(plan))
+        return self._converted[ck]
 
 
 #: Module-level dispatcher behind the one-call public API.

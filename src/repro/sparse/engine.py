@@ -114,7 +114,7 @@ def coalesce_budget(plan: StreamPlan, *,
       alive);
     * the batch replays through ``execute_wide`` at the plan's
       ``coalesce_block_d``, so per-launch kernel tiling (including the
-      CSR B-slab packed for ``plan_d``) is unchanged by coalescing — the
+      binned B slab packed for ``plan_d``) is unchanged by coalescing — the
       budget never needs to model VMEM, only host staging.
 
     The result is floored at the planned width (a planned-width request

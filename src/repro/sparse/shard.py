@@ -35,7 +35,7 @@ shard plus the strategy's collective cost
 ``HardwareSpec.collective_bandwidth``), with skip/selection reasons
 recorded in :meth:`ShardedPlan.summary`.
 
-Execution runs under ``jax.experimental.shard_map`` over a 1-D flattening
+Execution runs under ``jax.shard_map`` over a 1-D flattening
 of the caller's mesh, reusing the registry's jax-backend
 ``KernelSpec.run`` unchanged inside each shard for CSR/ELL/BCSR (padded
 per-shard layouts are stacked on a leading device axis).  DIA is the one
@@ -52,7 +52,6 @@ from typing import Callable, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import sparsity_models as sm
@@ -176,7 +175,7 @@ class ShardedPlan(_stream.StreamPlan):
         disp, m, plan = self._dispatcher, self._m, self.dispatch
         fmt_name, d, n, nnz = plan.chosen, plan.d, m.n, max(m.nnz, 1)
         D = self.num_shards
-        hw = disp._resolve_hardware(plan.backend)
+        hw = disp._resolve_hardware()
         prec = self._exec_precision()
         sv = prec.sizeof_val
         cand = plan.candidate(fmt_name)
@@ -290,7 +289,7 @@ class ShardedPlan(_stream.StreamPlan):
             return disp.convert(mm, format, precision=_prec)
 
         return registry.KernelContext(
-            hardware=disp._resolve_hardware(plan.backend),
+            hardware=disp._resolve_hardware(),
             bcsr_block=disp.bcsr_block,
             max_dia_offsets=disp.max_dia_offsets,
             plan_d=plan.d, precision=prec, convert=_convert)
@@ -408,21 +407,21 @@ class ShardedPlan(_stream.StreamPlan):
         ).astype(np.int32))
 
         if self.b_strategy == "replicate":
-            body = shard_map(
+            body = jax.shard_map(
                 lambda a, b: local(a, b)[None], mesh=mesh,
                 in_specs=(P(SHARD_AXIS), P()), out_specs=P(SHARD_AXIS),
-                check_rep=False)
+                check_vma=False)
 
             def run_impl(arrs, b):
                 return body(arrs, b).reshape(D * R, -1)[gidx]
         else:                               # all_gather
             Rb = -(-n // D)
-            body = shard_map(
+            body = jax.shard_map(
                 lambda a, b: local(
                     a, jax.lax.all_gather(b, SHARD_AXIS, tiled=True)[:n]
                 )[None],
                 mesh=mesh, in_specs=(P(SHARD_AXIS), P(SHARD_AXIS)),
-                out_specs=P(SHARD_AXIS), check_rep=False)
+                out_specs=P(SHARD_AXIS), check_vma=False)
 
             def run_impl(arrs, b):
                 b_pad = jnp.pad(b, ((0, D * Rb - n), (0, 0)))
@@ -539,9 +538,9 @@ class ShardedPlan(_stream.StreamPlan):
             return jax.lax.psum_scatter(partial, SHARD_AXIS,
                                         scatter_dimension=0, tiled=True)
 
-        body = shard_map(body_fn, mesh=mesh,
+        body = jax.shard_map(body_fn, mesh=mesh,
                          in_specs=(P(SHARD_AXIS), P(SHARD_AXIS)),
-                         out_specs=P(SHARD_AXIS), check_rep=False)
+                         out_specs=P(SHARD_AXIS), check_vma=False)
         b_lo = [int(x) for x in bounds[:-1]]
         b_hi = [int(x) for x in bounds[1:]]
 
@@ -589,10 +588,10 @@ class ShardedPlan(_stream.StreamPlan):
             return contrib.sum(0).astype(b_full.dtype)   # [n, d]
 
         if self.b_strategy == "replicate":
-            body = shard_map(
+            body = jax.shard_map(
                 lambda a, b: jax.lax.psum(partial_fn(a, b), SHARD_AXIS),
                 mesh=mesh, in_specs=(P(SHARD_AXIS), P()), out_specs=P(),
-                check_rep=False)
+                check_vma=False)
 
             def run_impl(arrs, b):
                 return body(arrs, b)
@@ -606,9 +605,9 @@ class ShardedPlan(_stream.StreamPlan):
                                             scatter_dimension=0,
                                             tiled=True)
 
-            body = shard_map(body_fn, mesh=mesh,
+            body = jax.shard_map(body_fn, mesh=mesh,
                              in_specs=(P(SHARD_AXIS), P()),
-                             out_specs=P(SHARD_AXIS), check_rep=False)
+                             out_specs=P(SHARD_AXIS), check_vma=False)
 
             def run_impl(arrs, b):
                 return body(arrs, b)[:n]

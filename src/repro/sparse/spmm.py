@@ -20,8 +20,9 @@ but traverse different host-prepared orders:
 All return C = A @ B with C: [n, d] in the operand dtype.  Reduced
 precisions (bf16 containers + bf16 B) round only the *products*:
 every accumulation runs in fp32 (explicit upcast before the segment
-sum / scan carry, ``preferred_element_type`` on the matmuls) and the
-result is cast back once at the end — the same contract as the Pallas
+sum / scan carry, ``preferred_element_type`` on the matmuls, which run
+at ``Precision.HIGHEST`` so a TPU does not round fp32 blocks to bf16) and
+the result is cast back once at the end — the same contract as the Pallas
 kernels' fp32 VMEM accumulators.
 """
 from __future__ import annotations
@@ -73,6 +74,7 @@ def bcsr_spmm(a: BCSRMatrix, b: jnp.ndarray) -> jnp.ndarray:
     b_tiles = b.reshape(a.nb, a.t, d)
     gathered = b_tiles[a.block_cols]              # [N, t, d]
     prods = jnp.einsum("nij,njd->nid", a.blocks, gathered,
+                       precision=jax.lax.Precision.HIGHEST,
                        preferred_element_type=jnp.float32)
     out_tiles = jax.ops.segment_sum(prods, a.block_rows, num_segments=a.nb)
     return out_tiles.reshape(a.n, d).astype(b.dtype)
@@ -118,6 +120,7 @@ def bcsr_spmm_scan(a: BCSRMatrix, b: jnp.ndarray,
     def _step(acc, blk):
         block, br, bc = blk
         prod = jnp.dot(block, b_tiles[bc],
+                       precision=jax.lax.Precision.HIGHEST,
                        preferred_element_type=jnp.float32)
         acc = acc.at[br].add(prod)
         return acc, None

@@ -344,3 +344,49 @@ def test_auto_within_ratio_of_best_fixed():
     claims = dispatch_claims_check(results)
     failed = [k for k, v in claims.items() if not v]
     assert not failed, f"dispatch claims failed: {failed}"
+
+
+def _tpu_with(**fields):
+    import dataclasses
+    from repro.core.hardware import TPU_V5E
+    return dataclasses.replace(TPU_V5E, **fields)
+
+
+def test_pallas_candidate_over_vmem_budget_is_skipped():
+    """Row-split holds all of B in VMEM: past the kernel budget the
+    dispatcher skips it with the reason, and forcing it is refused."""
+    hw = _tpu_with(vmem_bytes=2 * 2 ** 20)       # 1 MiB kernel budget
+    m = erdos_renyi(4096, 8, seed=21)
+    disp = sparse.Dispatcher(hardware=hw, backend="pallas",
+                             calibration=False, tree=False)
+    plan = disp.plan(m, 64)
+    assert "rowsplit" in plan.skips
+    assert "VMEM footprint" in plan.skips["rowsplit"]
+    assert "1 MiB kernel budget" in plan.skips["rowsplit"]
+    assert plan.chosen != "rowsplit"
+    assert plan.candidate("csr").eligible       # B in HBM: no VMEM limit
+    with pytest.raises(ValueError, match="VMEM footprint"):
+        disp.plan(m, 64, strategy="rowsplit")
+
+
+def test_pallas_binned_packing_and_smem_gates():
+    """Binned visits padded past MAX_PACKED_INFLATION and metadata over
+    the SMEM budget are device-limit skips with recorded reasons."""
+    from repro.sparse.dispatch import MAX_PACKED_INFLATION
+    # 128 KiB budget: 4096-row slabs cut each row tile's 8 nonzeros of a
+    # 16384-row ER matrix into one-nonzero visits.
+    hw = _tpu_with(vmem_bytes=256 * 2 ** 10)
+    m = erdos_renyi(16384, 8, seed=22)
+    disp = sparse.Dispatcher(hardware=hw, backend="pallas",
+                             calibration=False, tree=False)
+    plan = disp.plan(m, 8)
+    assert "binned packing" in plan.skips["binned"]
+    assert f"limit {MAX_PACKED_INFLATION}x" in plan.skips["binned"]
+    tiny_smem = _tpu_with(smem_bytes=1024)      # 768 B of metadata
+    plan = sparse.Dispatcher(hardware=tiny_smem, backend="pallas",
+                             calibration=False, tree=False).plan(m, 8)
+    assert "SMEM budget" in plan.skips["csr"]
+    # The jax backend has neither limit.
+    jplan = sparse.Dispatcher(hardware=tiny_smem, backend="jax",
+                              calibration=False, tree=False).plan(m, 8)
+    assert "csr" not in jplan.skips and "binned" not in jplan.skips

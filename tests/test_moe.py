@@ -67,7 +67,8 @@ import jax, jax.numpy as jnp, numpy as np
 from repro.models import moe
 from repro.models.sharding_ctx import ShardingCtx
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 params = moe.init_moe(jax.random.PRNGKey(0), 16, 32, 8)
 x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, 16), jnp.float32)
 ctx = ShardingCtx({}, mesh)
@@ -95,5 +96,6 @@ print("SHARDED-MOE-OK")
 def test_shard_map_moe_multi_device():
     r = subprocess.run([sys.executable, "-c", _SHARD_SCRIPT],
                        capture_output=True, text=True, timeout=500,
-                       env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin"})
+                       env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
+                            "JAX_PLATFORMS": "cpu"})
     assert "SHARDED-MOE-OK" in r.stdout, r.stderr[-2000:]

@@ -14,7 +14,8 @@ import jax, jax.numpy as jnp, numpy as np
 from repro.train.pipeline import pipeline_apply, split_stages
 
 S, L, D, N_MICRO, MB = 4, 8, 16, 6, 4
-mesh = jax.make_mesh((S,), ("stage",))
+mesh = jax.make_mesh((S,), ("stage",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 key = jax.random.PRNGKey(0)
 Ws = jax.random.normal(key, (L, D, D)) * (1.0 / np.sqrt(D))
 
@@ -61,5 +62,6 @@ print("PIPELINE-OK")
 def test_pipeline_multi_device_equivalence():
     r = subprocess.run([sys.executable, "-c", _SCRIPT],
                        capture_output=True, text=True, timeout=500,
-                       env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin"})
+                       env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
+                            "JAX_PLATFORMS": "cpu"})
     assert "PIPELINE-OK" in r.stdout, (r.stdout[-1000:], r.stderr[-2000:])

@@ -77,3 +77,22 @@ def test_real_dryrun_records_if_present():
         assert r["memory_s"] > 0
         assert rec["chips"] in (256, 512)
         assert rec["memory"]["temp_size_in_bytes"] >= 0
+
+
+def test_device_kind_table():
+    """One table maps a device kind to its spec; unknown kinds raise."""
+    from repro.core.hardware import HOST_CPU, for_device_kind
+    assert for_device_kind("TPU v5 lite") is TPU_V5E
+    assert for_device_kind("cpu") is HOST_CPU
+    for unknown in ("TPU v4", "TPU v6 lite", "NVIDIA H100"):
+        with pytest.raises(ValueError, match="no HardwareSpec"):
+            for_device_kind(unknown)
+
+
+def test_kernel_memory_limits_fit_the_chip():
+    """Kernels request half the v5e VMEM; metadata gets 3/4 of SMEM."""
+    from repro.core.hardware import (HOST_CPU, kernel_smem_limit,
+                                     kernel_vmem_limit)
+    assert kernel_vmem_limit(TPU_V5E) == 64 * 2 ** 20
+    assert kernel_smem_limit(TPU_V5E) == 768 * 2 ** 10
+    assert kernel_smem_limit(HOST_CPU) == 0          # no SMEM on the host
