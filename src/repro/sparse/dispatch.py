@@ -55,6 +55,7 @@ from repro.core.hardware import (
 from repro.core.roofline import ComputeCeiling
 from repro.core import sparsity_models as sm
 from repro.core.patterns import COOMatrix
+from repro import obs
 from repro.sparse import formats as fmt
 
 FORMATS: Tuple[str, ...] = ("csr", "ell", "bcsr", "dia",
@@ -353,7 +354,8 @@ class Dispatcher:
     def _report(self, m: COOMatrix) -> StructureReport:
         key = self._track(m)
         if key not in self._reports:
-            self._reports[key] = classify(m)
+            with obs.span("repro.dispatch.classify"):
+                self._reports[key] = classify(m)
         return self._reports[key]
 
     def convert(self, m: COOMatrix, format: str, precision=None):
@@ -729,6 +731,45 @@ class Dispatcher:
                 f"to opt in")
         return True, None
 
+    def _score(self, m: COOMatrix, report: StructureReport, d: int,
+               hw: HardwareSpec, reuse: int, backend: str, tolerance: float,
+               forced_tok: Optional[str]) -> list:
+        """One :class:`CandidateEval` per (format, precision) row the
+        backend's kernels support, each timed as a
+        ``repro.dispatch.candidate`` span."""
+        from repro.kernels import registry as kreg
+        cands = []
+        for f in FORMATS:
+            eligible, reason, params = self._policy(m, report, f)
+            if eligible and backend == "pallas":
+                reason = self._pallas_gate(m, f, d, hw)
+                eligible = reason is None
+            spec_tokens = kreg.get(f, backend).supported_precisions
+            for prec in PRECISIONS:
+                if prec.token not in spec_tokens:
+                    continue
+                with obs.span("repro.dispatch.candidate", format=f,
+                              precision=prec.token):
+                    p_ok, p_reason = self._precision_gate(
+                        m, f, prec, d, hw, backend, tolerance,
+                        forced=prec.token == forced_tok)
+                    source = "default"
+                    row_params = dict(params)
+                    try:
+                        ai, useful, pred, amort, conv, source = self._model(
+                            m, report, f, row_params, d, hw, reuse, backend,
+                            prec)
+                    except (KeyError, ValueError):
+                        ai = useful = pred = amort = conv = None
+                    cands.append(CandidateEval(
+                        format=f, eligible=eligible and p_ok,
+                        skip_reason=reason if not eligible else p_reason,
+                        ai=ai, useful_fraction=useful, predicted_gflops=pred,
+                        amortized_gflops=amort, conversion_bytes=conv,
+                        params=row_params, ceiling_source=source,
+                        precision=prec.token))
+        return cands
+
     # ----------------------------------------------------------------- #
     # Public API
     # ----------------------------------------------------------------- #
@@ -798,36 +839,10 @@ class Dispatcher:
         if key in self._plans:
             return self._plans[key]
 
-        from repro.kernels import registry as kreg
         report = self._report(m)
-        cands = []
-        for f in FORMATS:
-            eligible, reason, params = self._policy(m, report, f)
-            if eligible and backend == "pallas":
-                reason = self._pallas_gate(m, f, d, hw)
-                eligible = reason is None
-            spec_tokens = kreg.get(f, backend).supported_precisions
-            for prec in PRECISIONS:
-                if prec.token not in spec_tokens:
-                    continue
-                p_ok, p_reason = self._precision_gate(
-                    m, f, prec, d, hw, backend, tolerance,
-                    forced=prec.token == forced_tok)
-                source = "default"
-                row_params = dict(params)
-                try:
-                    ai, useful, pred, amort, conv, source = self._model(
-                        m, report, f, row_params, d, hw, reuse, backend,
-                        prec)
-                except (KeyError, ValueError):
-                    ai = useful = pred = amort = conv = None
-                cands.append(CandidateEval(
-                    format=f, eligible=eligible and p_ok,
-                    skip_reason=reason if not eligible else p_reason,
-                    ai=ai, useful_fraction=useful, predicted_gflops=pred,
-                    amortized_gflops=amort, conversion_bytes=conv,
-                    params=row_params, ceiling_source=source,
-                    precision=prec.token))
+        with obs.span("repro.dispatch.score"):
+            cands = self._score(m, report, d, hw, reuse, backend, tolerance,
+                                forced_tok)
 
         pool = cands if forced_tok is None else \
             [c for c in cands if c.precision == forced_tok]
@@ -993,8 +1008,10 @@ class Dispatcher:
               self.bcsr_block, registry.pallas_block_d(plan.d),
               prec.token)
         if ck not in self._converted:
-            self._converted[ck] = spec.prepare(m,
-                                               self._kernel_context(plan))
+            with obs.span("repro.dispatch.prepare", format=plan.chosen,
+                          precision=prec.token):
+                self._converted[ck] = spec.prepare(
+                    m, self._kernel_context(plan))
         return self._converted[ck]
 
 

@@ -51,6 +51,13 @@ The serving loop is four stages, each inspectable in :meth:`ServingEngine
    atomically under the queue lock; in-flight batches keep the plan they
    were drafted against.
 
+Each stage is timed on the worker thread as a ``repro.engine.*`` span
+(:mod:`repro.obs`): ``draft``, ``stage``, ``dispatch`` (the enqueue of
+the launch), ``wait`` (``block_until_ready``), ``fetch`` (the copy to the
+host) and ``complete``, each with attr ``batch`` (``BatchRecord.seq``),
+``idle`` while the worker waits for work, and ``submit`` on the caller's
+thread with attr ``request`` (the ticket id).
+
 Latency accounting (the numbers ``stats`` reports): a request's latency
 is measured from the ``submit`` call's entry (so backpressure wait is
 *included* — it is part of what the client observes) to the completion of
@@ -72,6 +79,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.precision import as_precision
 from repro.sparse.stream import StreamPlan
 
@@ -224,7 +232,7 @@ class BatchRecord:
     which operator, which requests, how wide, how long.
     """
 
-    seq: int
+    seq: int                      # batch number, in draft order
     operator: str
     chosen: str                   # format the plan executed
     request_ids: Tuple[int, ...]
@@ -239,6 +247,7 @@ class BatchRecord:
 class _Staged:
     """A drafted batch staged on device, awaiting dispatch."""
 
+    seq: int                      # the batch's number, from its draft
     plan: StreamPlan
     requests: List[_Request]
     b_dev: jnp.ndarray
@@ -418,6 +427,14 @@ class ServingEngine:
             ValueError: operand shape incompatible with the plan.
             ShedError: queue full under ``"shed"``, or wait timed out.
         """
+        with obs.span("repro.engine.submit") as attrs:
+            ticket = self._admit(operator, b, deadline_s, timeout)
+            attrs["request"] = ticket.id
+        return ticket
+
+    def _admit(self, operator: str, b: jnp.ndarray,
+               deadline_s: Optional[float],
+               timeout: Optional[float]) -> Ticket:
         t0 = self.clock()
         with self._lock:
             plan = self._plans[operator]        # KeyError = unknown operator
@@ -460,8 +477,8 @@ class ServingEngine:
     # Coalescing + staging (stages 2-3)
     # ------------------------------------------------------------- #
 
-    def _draft(self) -> Optional[Tuple[StreamPlan, List[_Request]]]:
-        """Pop the next micro-batch from the queue (stage 2, under lock).
+    def _draft(self) -> Optional[Tuple[StreamPlan, List[_Request], int]]:
+        """Pop the next micro-batch and number it (stage 2, under lock).
 
         The queue head anchors the batch; every other queued request for
         the same operator joins in FIFO order until the column budget is
@@ -472,27 +489,30 @@ class ServingEngine:
         with self._lock:
             if not self._queue:
                 return None
-            head = self._queue.popleft()
-            op = head.ticket.operator
-            plan = self._plans[op]
-            budget = (max(self.max_batch_cols, plan.spec.d)
-                      if self.max_batch_cols is not None
-                      else coalesce_budget(plan,
-                                           stage_bytes=self.stage_bytes))
-            batch = [head]
-            cols = head.ticket.d
-            rest: List[_Request] = []
-            while self._queue:
-                req = self._queue.popleft()
-                if (req.ticket.operator == op
-                        and cols + req.ticket.d <= budget):
-                    batch.append(req)
-                    cols += req.ticket.d
-                else:
-                    rest.append(req)
-            self._queue.extend(rest)
-            self._space.notify_all()
-            return plan, batch
+            seq = self._batch_seq
+            self._batch_seq += 1
+            with obs.span("repro.engine.draft", batch=seq):
+                head = self._queue.popleft()
+                op = head.ticket.operator
+                plan = self._plans[op]
+                budget = (max(self.max_batch_cols, plan.spec.d)
+                          if self.max_batch_cols is not None
+                          else coalesce_budget(plan,
+                                               stage_bytes=self.stage_bytes))
+                batch = [head]
+                cols = head.ticket.d
+                rest: List[_Request] = []
+                while self._queue:
+                    req = self._queue.popleft()
+                    if (req.ticket.operator == op
+                            and cols + req.ticket.d <= budget):
+                        batch.append(req)
+                        cols += req.ticket.d
+                    else:
+                        rest.append(req)
+                self._queue.extend(rest)
+                self._space.notify_all()
+                return plan, batch, seq
 
     def _stage(self) -> Optional[_Staged]:
         """Draft the next batch and move its operand to device (stage 3).
@@ -505,30 +525,31 @@ class ServingEngine:
         drafted = self._draft()
         if drafted is None:
             return None
-        plan, batch = drafted
-        t_batch = self.clock()
-        for req in batch:
-            req.ticket.batched_s = t_batch
-        cols = sum(r.ticket.d for r in batch)
-        block_d = plan.coalesce_block_d(cols)
-        pad = (-cols) % block_d
-        # Concatenate on the host (NumPy), not with jnp: an eager
-        # jnp.concatenate compiles one XLA program per distinct
-        # width-combination, and arrival timing makes nearly every batch
-        # a new combination — recompiles would dominate the batch.  One
-        # memcpy-shaped concat plus a single device_put is the staging
-        # transfer the double buffering exists to overlap.  Staging casts
-        # to the plan's precision dtype here, on the host, so a bf16 plan
-        # moves half the bytes per batch.
-        stage_dt = np.dtype(_stage_dtype(plan))
-        parts = [np.asarray(r.b, dtype=stage_dt) for r in batch]
-        if pad:
-            parts.append(np.zeros((plan.n, pad), stage_dt))
-        wide = parts[0] if len(parts) == 1 else np.concatenate(
-            parts, axis=1)
-        return _Staged(plan=plan, requests=batch,
-                       b_dev=jax.device_put(wide), block_d=block_d,
-                       cols=cols + pad)
+        plan, batch, seq = drafted
+        with obs.span("repro.engine.stage", batch=seq):
+            t_batch = self.clock()
+            for req in batch:
+                req.ticket.batched_s = t_batch
+            cols = sum(r.ticket.d for r in batch)
+            block_d = plan.coalesce_block_d(cols)
+            pad = (-cols) % block_d
+            # Concatenate on the host (NumPy), not with jnp: an eager
+            # jnp.concatenate compiles one XLA program per distinct
+            # width-combination, and arrival timing makes nearly every batch
+            # a new combination — recompiles would dominate the batch.  One
+            # memcpy-shaped concat plus a single device_put is the staging
+            # transfer the double buffering exists to overlap.  Staging casts
+            # to the plan's precision dtype here, on the host, so a bf16 plan
+            # moves half the bytes per batch.
+            stage_dt = np.dtype(_stage_dtype(plan))
+            parts = [np.asarray(r.b, dtype=stage_dt) for r in batch]
+            if pad:
+                parts.append(np.zeros((plan.n, pad), stage_dt))
+            wide = parts[0] if len(parts) == 1 else np.concatenate(
+                parts, axis=1)
+            return _Staged(seq=seq, plan=plan, requests=batch,
+                           b_dev=jax.device_put(wide), block_d=block_d,
+                           cols=cols + pad)
 
     # ------------------------------------------------------------- #
     # Execution (stage 4)
@@ -550,17 +571,20 @@ class ServingEngine:
             staged = self._stage()
         if staged is None:
             return 0
-        plan, batch = staged.plan, staged.requests
+        plan, batch, seq = staged.plan, staged.requests, staged.seq
         hints = plan.exec_hints()
         try:
-            out = plan.execute_wide(staged.b_dev, block_d=staged.block_d)
+            with obs.span("repro.engine.dispatch", batch=seq):
+                out = plan.execute_wide(staged.b_dev,
+                                        block_d=staged.block_d)
             if hints.get("donate_b"):
                 # The launch consumed the staged buffer; drop our alias
                 # now rather than at materialization.
                 staged.b_dev = None
             if self.double_buffer and hints.get("async_dispatch", True):
                 self._staged = self._stage()    # overlaps device compute
-            jax.block_until_ready(out)
+            with obs.span("repro.engine.wait", batch=seq):
+                jax.block_until_ready(out)
         except Exception as exc:               # noqa: BLE001 - delivered
             t_done = self.clock()
             for req in batch:
@@ -572,7 +596,16 @@ class ServingEngine:
         # eager jnp slices compile per (offset, width) pair, so a mixed
         # batch would pay a compile per ticket; NumPy views are free and
         # the batch is already synced.
-        host = np.asarray(out)
+        with obs.span("repro.engine.fetch", batch=seq):
+            host = np.asarray(out)
+        with obs.span("repro.engine.complete", batch=seq):
+            self._complete(staged, host)
+        return len(batch)
+
+    def _complete(self, staged: _Staged, host: np.ndarray) -> None:
+        """Hand each ticket its columns of ``host``, account for the
+        batch, and poll for a plan swap."""
+        batch = staged.requests
         t_done = self.clock()
         lo = 0
         for req in batch:
@@ -580,10 +613,9 @@ class ServingEngine:
             tk._result = host[:, lo:lo + tk.d]
             lo += tk.d
             tk.done_s = t_done
-            tk.batch_seq = self._batch_seq
+            tk.batch_seq = staged.seq
             tk._event.set()
         with self._lock:
-            self._batch_seq += 1
             self._counts["batches"] += 1
             self._counts["served"] += len(batch)
             if len(batch) > 1:
@@ -594,8 +626,8 @@ class ServingEngine:
             self._last_done_s = t_done
             oldest = min(r.ticket.submitted_s for r in batch)
             self.batch_log.append(BatchRecord(
-                seq=self._batch_seq - 1, operator=batch[0].ticket.operator,
-                chosen=plan.chosen,
+                seq=staged.seq, operator=batch[0].ticket.operator,
+                chosen=staged.plan.chosen,
                 request_ids=tuple(r.ticket.id for r in batch),
                 widths=tuple(r.ticket.d for r in batch),
                 cols=staged.cols, block_d=staged.block_d,
@@ -603,7 +635,6 @@ class ServingEngine:
                 exec_s=t_done - batch[0].ticket.batched_s))
         if self.auto_replan:
             self._maybe_swap(batch[0].ticket.operator)
-        return len(batch)
 
     def _maybe_swap(self, operator: str) -> None:
         """Atomic mid-stream plan swap when the reuse audit fired.
@@ -674,6 +705,10 @@ class ServingEngine:
             self._thread.join(timeout)
             self._thread = None
 
+    def _nothing_to_do(self) -> bool:
+        return not self._queue and self._staged is None \
+            and not self._stopping
+
     def _worker(self) -> None:
         """Worker loop: wait for admissions, serve batches until stopped.
 
@@ -684,9 +719,10 @@ class ServingEngine:
         """
         while True:
             with self._work:
-                while (not self._queue and self._staged is None
-                       and not self._stopping):
-                    self._work.wait(0.1)
+                if self._nothing_to_do():
+                    with obs.span("repro.engine.idle"):
+                        while self._nothing_to_do():
+                            self._work.wait(0.1)
                 if self._stopping and (
                         not getattr(self, "_drain_on_stop", True)
                         or not self._queue):

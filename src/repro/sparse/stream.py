@@ -37,6 +37,7 @@ from typing import Any, Iterable, Optional, Sequence, Union
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.patterns import COOMatrix
 from repro.sparse import dispatch as _dispatch
 
@@ -145,7 +146,8 @@ class StreamPlan:
         # execute.  (The first execute still pays the kernel's one-time
         # XLA compile for this shape — latency-sensitive servers should
         # warm up with one batch, as launch/serve.py does.)
-        self._run = self._bind()
+        with obs.span("repro.plan.bind"):
+            self._run = self._bind()
         self.executed = 0
         self._reuse_warned = False
 
@@ -194,10 +196,13 @@ class StreamPlan:
             b: dense right-hand side, ``[n, spec.d]``.
 
         Returns:
-            ``C`` as a dense ``[n, spec.d]`` array.
+            ``C`` as a dense ``[n, spec.d]`` array.  The call is logged as
+            a ``repro.execute`` span (:mod:`repro.obs`) that times the
+            host side: the launch is enqueued, not waited for.
         """
         self._check(b, width=self.spec.d)
-        out = self._run(b)
+        with obs.span("repro.execute", format=self.chosen):
+            out = self._run(b)
         self.executed += 1          # count only replays that succeeded
         self._audit_reuse()
         return out
@@ -217,10 +222,12 @@ class StreamPlan:
             b: dense right-hand side, ``[n, spec.d]``.
 
         Returns:
-            ``C`` as an un-materialized ``[n, spec.d]`` device array.
+            ``C`` as an un-materialized ``[n, spec.d]`` device array; the
+            ``repro.execute`` span times the enqueue only.
         """
         self._check(b, width=self.spec.d)
-        out = self._run(b)
+        with obs.span("repro.execute", format=self.chosen):
+            out = self._run(b)
         self.executed += 1
         self._audit_reuse()
         return out
@@ -403,7 +410,8 @@ class StreamPlan:
             block_d: column block width; defaults to ``spec.d``.
 
         Returns:
-            ``C`` as a dense ``[n, D]`` array.
+            ``C`` as a dense ``[n, D]`` array.  One ``repro.execute`` span
+            times the host side of all the blocks' launches.
         """
         self._check(b)
         block_d = self.spec.d if block_d is None else int(block_d)
@@ -412,12 +420,14 @@ class StreamPlan:
         total = b.shape[1]
         if total == 0:
             return jnp.zeros((self.n, 0), dtype=b.dtype)
-        outs = []
-        for lo in range(0, total, block_d):
-            outs.append(self._run(b[:, lo:lo + block_d]))
-            self.executed += 1
+        with obs.span("repro.execute", format=self.chosen):
+            outs = []
+            for lo in range(0, total, block_d):
+                outs.append(self._run(b[:, lo:lo + block_d]))
+                self.executed += 1
+            out = jnp.concatenate(outs, axis=1)
         self._audit_reuse()
-        return jnp.concatenate(outs, axis=1)
+        return out
 
     def reset_stats(self) -> None:
         """Zero the execution counter (e.g. after warm-up calls, so
