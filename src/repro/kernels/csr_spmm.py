@@ -8,17 +8,44 @@ segment sum becomes an MXU matmul:
   * rows are grouped into tiles of ``row_tile`` rows; each tile's nonzeros
     are padded to whole chunks of ``chunk`` entries (sliced-ELL style
     packing of the CSR arrays, built host-side by ``csr_to_row_tiles``);
-  * one grid step owns one row tile's C block and loops over the tile's
+  * one grid step owns one row tile's C block and reduces the tile's
     chunks (their range arrives via scalar prefetch, one int per tile:
     SMEM holds 1 MiB, too little for one int per chunk at n = 2**20);
-  * per chunk, the column ids are DMA'd into SMEM, the ``chunk`` rows of
-    B they name are DMA'd straight from HBM into a VMEM gather buffer,
-    and one ``[row_tile, chunk] @ [chunk, bd]`` matmul — its left operand
-    the value-weighted one-hot of the nonzeros' row slots — reduces them
-    into the tile.
+  * per chunk, the ``chunk`` rows of B its column ids name are DMA'd
+    straight from HBM into a VMEM gather slot, and one
+    ``[row_tile, chunk] @ [chunk, bd]`` matmul — its left operand the
+    value-weighted one-hot of the nonzeros' row slots — reduces them into
+    the tile.
 
-B never has to fit VMEM: only the ``[chunk, bd]`` gather buffer is
+B never has to fit VMEM: only the two ``[chunk, bd]`` gather slots are
 resident, so the layout needs no B slabs and column ids stay global.
+
+The chunk loop is a software pipeline over the global chunk sequence
+(every tile's chunks in tile order, ``tile_starts[T]`` in all), because a
+chunk run in series pays its metadata round trip, the latency of its
+slowest row DMA and its matmul back to back:
+
+  * two gather slots, each with its own DMA semaphore: while chunk ``c``
+    is waited on and reduced from slot ``c % 2``, the row DMAs of chunk
+    ``c + 1`` are already issued into the other;
+  * metadata further ahead: chunk ``c + 2``'s column ids are fetched into
+    SMEM (two entries) while chunk ``c + 1``'s rows are issued, and
+    ``c + 1``'s row slots and values (two VMEM entries each) follow its
+    rows;
+  * one wait per chunk: DMA semaphores count bytes, so a wait on a
+    descriptor the size of the whole slot covers its ``chunk`` row copies;
+  * across tiles: the pipeline's state lives in scratch and both grid
+    axes run in order (``arbitrary``), so the last chunk of a tile
+    requests the first chunk of the next nonempty tile, however many
+    empty tiles lie between; only the first chunk of each d-pass is
+    requested cold (``pipeline_counts``);
+  * every DMA started is waited on: a chunk requests the next only if
+    ``c + 1 < tile_starts[T]``, so the last chunk of a d-pass drains the
+    pipeline, and the all-empty matrix's one padding chunk, which no tile
+    owns, is never fetched.
+
+B is passed as ``[d / bd, n, bd]`` so that a row DMA takes whole rows of
+one d-pass's slice (a free reshape at one pass, a copy of B at several).
 
 The gathered rows are fp32 (B is upcast before the call): Mosaic cannot
 address single rows of a packed bf16 tile.  bf16 precisions still store
@@ -35,6 +62,7 @@ empty rows come out zero.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Tuple
 
 import jax
@@ -199,11 +227,12 @@ def chunk_spec(array, index_map, smem: bool = False) -> pl.BlockSpec:
     return pl.BlockSpec(shape, full)
 
 
-def chunk_scratch(cols, slots, vals) -> list:
-    """Scratch for one entry of the columns (SMEM), slots and values."""
-    return [pltpu.SMEM((1,) + tuple(cols.shape[1:]), cols.dtype),
-            pltpu.VMEM((1,) + tuple(slots.shape[1:]), slots.dtype),
-            pltpu.VMEM((1,) + tuple(vals.shape[1:]), vals.dtype)]
+def chunk_scratch(cols, slots, vals, entries: int = 1) -> list:
+    """Scratch for ``entries`` entries each of the columns (SMEM), slots
+    and values."""
+    return [pltpu.SMEM((entries,) + tuple(cols.shape[1:]), cols.dtype),
+            pltpu.VMEM((entries,) + tuple(slots.shape[1:]), slots.dtype),
+            pltpu.VMEM((entries,) + tuple(vals.shape[1:]), vals.dtype)]
 
 
 def load_chunk(c, hbm_refs, bufs, sems) -> None:
@@ -225,40 +254,101 @@ def for_each_chunk(owner: int, starts_ref, body) -> None:
     jax.lax.fori_loop(starts_ref[owner], starts_ref[owner + 1], step, 0)
 
 
+#: DMA starts per iteration of the row-issue loop (Mosaic unrolls a
+#: ``fori_loop`` only fully, so the loop body issues this many itself).
+#: On a v5e at 128-slot chunks the pipelined kernel took 3.39 / 2.58 /
+#: 2.25 / 2.15 / 2.09 / 2.06 / 2.05 / 2.43 us a chunk at 1 / 2 / 4 / 8 /
+#: 16 / 32 / 64 / 128 starts per iteration.
+ISSUE_UNROLL: int = 32
+
+# Semaphore kinds, one of each per pipeline slot.
+_ROWS, _COLS, _META = 0, 1, 2
+
+
+def pipeline_counts(chunks: int, d: int, block_d: int) -> dict:
+    """Static counts of one ``csr_spmm_pallas`` launch.
+
+    ``chunks`` is ``tile_starts[-1]``.  Every d-pass reduces all of them;
+    ``cold_chunks`` are those whose B rows were not requested while an
+    earlier chunk reduced: the first of each d-pass.
+    """
+    passes = d // min(block_d, d)
+    return {"chunks": passes * chunks,
+            "cold_chunks": passes * min(chunks, 1)}
+
+
 def _csr_kernel(starts_ref, cols_hbm, slots_hbm, vals_hbm, b_hbm, o_ref,
                 cols_buf, slots_buf, vals_buf, gbuf, sems, *,
                 row_tile: int):
     """One grid step: one row tile's C block, its chunks in a loop.
 
-    Each chunk's metadata is DMA'd into SMEM/VMEM, then its B rows are
-    DMA'd from HBM into ``gbuf`` and reduced into the tile.
+    ``reduce(c)`` finds chunk ``c``'s rows in flight in slot ``c % 2`` and
+    the column ids of ``c + 1`` in flight into SMEM; it requests ``c + 1``
+    (which fetches the ids of ``c + 2``) before it waits on and reduces
+    ``c`` (see the module docstring).
     """
     tile = pl.program_id(1)
-    bd = o_ref.shape[1]
-    d0 = pl.program_id(0) * bd
+    b_pass = b_hbm.at[pl.program_id(0)]          # [n, bd]: this d-pass
+    total = starts_ref[pl.num_programs(1)]
+    chunk = gbuf.shape[1]
+    unroll = math.gcd(ISSUE_UNROLL, chunk)
     o_ref[...] = jnp.zeros_like(o_ref)
 
-    def row_copy(col, j):
-        return pltpu.make_async_copy(b_hbm.at[pl.ds(col, 1), pl.ds(d0, bd)],
-                                     gbuf.at[pl.ds(j, 1), :], sems.at[3])
+    def entry(c, hbm, buf, kind):
+        return pltpu.make_async_copy(hbm.at[pl.ds(c // hbm.shape[1], 1)],
+                                     buf.at[pl.ds(c % 2, 1)],
+                                     sems.at[c % 2, kind])
 
-    def chunk(c):
-        load_chunk(c, (cols_hbm, slots_hbm, vals_hbm),
-                   (cols_buf, slots_buf, vals_buf), sems)
+    def meta_copies(c):
+        return [entry(c, slots_hbm, slots_buf, _META),
+                entry(c, vals_hbm, vals_buf, _META)]
 
-        def start(j, carry):
-            row_copy(chunk_col(cols_buf, c, j), j).start()
+    def rows_copy(c, col, j):
+        return pltpu.make_async_copy(
+            b_pass.at[pl.ds(col, 1)],
+            gbuf.at[c % 2, pl.ds(j, 1), :], sems.at[c % 2, _ROWS])
+
+    def request(c):
+        """Issue chunk ``c``'s B rows; its column ids are in flight."""
+        entry(c, cols_hbm, cols_buf, _COLS).wait()
+
+        @pl.when(c + 1 < total)
+        def _():
+            entry(c + 1, cols_hbm, cols_buf, _COLS).start()
+
+        def issue(i, carry):
+            for k in range(unroll):
+                j = i * unroll + k
+                col = cols_buf[c % 2, c % cols_buf.shape[1], j]
+                rows_copy(c, col.astype(jnp.int32), j).start()
             return carry
 
-        jax.lax.fori_loop(0, gbuf.shape[0], start, 0)
-        jax.lax.fori_loop(0, gbuf.shape[0], wait, 0)
-        o_ref[...] += chunk_product(c, slots_buf, vals_buf, gbuf, row_tile)
+        jax.lax.fori_loop(0, chunk // unroll, issue, 0)
+        for cp in meta_copies(c):
+            cp.start()
 
-    def wait(j, carry):
-        row_copy(0, 0).wait()          # one row's bytes per wait
-        return carry
+    @pl.when((tile == 0) & (total > 0))
+    def _():                       # a d-pass starts: chunk 0 goes cold
+        first = starts_ref[0]      # 0, traced: chunk 1 may not exist
+        entry(first, cols_hbm, cols_buf, _COLS).start()
+        request(first)
 
-    for_each_chunk(tile, starts_ref, chunk)
+    def reduce(c):
+        @pl.when(c + 1 < total)
+        def _():
+            request(c + 1)
+
+        # DMA semaphores count bytes: one wait the size of the whole slot
+        # covers all of its row copies.
+        slot = gbuf.at[c % 2]
+        pltpu.make_async_copy(slot, slot, sems.at[c % 2, _ROWS]).wait()
+        for cp in meta_copies(c):
+            cp.wait()
+        o_ref[...] += chunk_product(c, slots_buf.at[pl.ds(c % 2, 1)],
+                                    vals_buf.at[pl.ds(c % 2, 1)], slot,
+                                    row_tile)
+
+    for_each_chunk(tile, starts_ref, reduce)
 
 
 @functools.partial(jax.jit,
@@ -289,6 +379,10 @@ def csr_spmm_pallas(tile_starts: jnp.ndarray, cols: jnp.ndarray,
     bd = min(block_d, d)
     if d % bd != 0:
         raise ValueError(f"d={d} must be divisible by the d-tile {bd}")
+    # [d / bd, n, bd]: a row DMA takes whole rows of one d-pass's slice
+    # (Mosaic refuses a one-row slice of a tiled HBM array that also
+    # slices its columns).  A copy of B only when there are several passes.
+    b = b.reshape(n, d // bd, bd).transpose(1, 0, 2)
     chunk = cols.shape[2]
     num_tiles = tile_starts.shape[0] - 1
     hbm = pl.BlockSpec(memory_space=pl.ANY)
@@ -298,16 +392,20 @@ def csr_spmm_pallas(tile_starts: jnp.ndarray, cols: jnp.ndarray,
         in_specs=[hbm, hbm, hbm, hbm],
         out_specs=pl.BlockSpec(
             (row_tile, bd), lambda i_d, t, starts: (t, i_d)),
-        scratch_shapes=chunk_scratch(cols, row_slots, vals) + [
-            pltpu.VMEM((chunk, bd), jnp.float32),
-            pltpu.SemaphoreType.DMA((4,))],
+        scratch_shapes=chunk_scratch(cols, row_slots, vals, entries=2) + [
+            pltpu.VMEM((2, chunk, bd), jnp.float32),
+            pltpu.SemaphoreType.DMA((2, 3))],
     )
     out = pl.pallas_call(
         functools.partial(_csr_kernel, row_tile=row_tile),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((num_tiles * row_tile, d),
                                        jnp.float32),
-        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit),
+        # The pipeline carries DMAs from one grid step to the next, so
+        # the steps run in order.
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=vmem_limit),
         interpret=interpret,
         name="csr_spmm",
     )(tile_starts, cols, row_slots, vals, b)

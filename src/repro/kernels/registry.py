@@ -51,7 +51,8 @@ from repro.kernels.bcsr_spmm import bcsr_spmm_pallas
 from repro.kernels.binned_spmm import (
     binned_spmm_pallas, csr_to_slab_bins, pack_rowsplit_chunks,
     rowsplit_spmm_pallas)
-from repro.kernels.csr_spmm import csr_spmm_pallas, csr_to_row_tiles
+from repro.kernels.csr_spmm import (csr_spmm_pallas, csr_to_row_tiles,
+                                    pipeline_counts)
 from repro.kernels.grouped_matmul import grouped_matmul_pallas
 
 BACKENDS: Tuple[str, ...] = ("jax", "pallas")
@@ -238,6 +239,11 @@ class KernelSpec:
     #: coordinates) the kernel holds in SMEM for matrix ``m``.
     smem_footprint: Callable[[Any, KernelContext], int] = \
         lambda m, ctx: 0
+    #: Static counts of one launch at width ``d`` from the prepared
+    #: layout, ``counters(layout, d) -> {name: int}``, logged as attrs of
+    #: the ``repro.execute`` span (``repro.sparse.stream``); None logs
+    #: none.
+    counters: Optional[Callable[[Any, int], Dict[str, int]]] = None
 
     def supports_precision(self, precision: Precision) -> bool:
         """True iff this kernel can execute at ``precision``."""
@@ -665,6 +671,7 @@ def _csr_pallas_prepare(m, ctx: KernelContext):
         np.asarray(csr.data), n=csr.n, row_tile=ctx.row_tile,
         chunk=ctx.chunk, index_dtype=ctx.precision.index_np)
     return {"n": csr.n, "row_tile": ctx.row_tile,
+            "chunks": int(arrays[0][-1]),
             "arrays": tuple(jnp.asarray(x) for x in arrays)}
 
 
@@ -685,22 +692,29 @@ def _csr_pallas_estimate(m, d, ctx: KernelContext) -> KernelRoofline:
         mxu_utilization=1.0)
 
 
-def _chunk_footprint(rows: int, bd: int, ctx: KernelContext) -> int:
+def _chunk_footprint(rows: int, bd: int, ctx: KernelContext,
+                     slots: int = 1) -> int:
     """VMEM of the CSR-family chunk machinery, double-buffered blocks.
 
-    The fp32 ``[chunk, bd]`` gather scratch, two buffers each of the
-    value, column and int8 slot chunks, and of the fp32 ``[rows, bd]``
-    output block.
+    ``slots`` fp32 ``[chunk, bd]`` gather buffers, two entries each of
+    the value, column and int8 slot chunks, and two buffers of the fp32
+    ``[rows, bd]`` output block.
     """
     p = ctx.precision
-    return (4 * ctx.chunk * bd
+    return (slots * 4 * ctx.chunk * bd
             + 2 * ctx.chunk * (p.sizeof_val + p.sizeof_idx + 1)
             + 2 * 4 * rows * bd)
 
 
 def _csr_pallas_footprint(n: int, d: int, ctx: KernelContext) -> int:
-    # B stays in HBM: nothing in the working set grows with n.
-    return _chunk_footprint(ctx.row_tile, min(512, pallas_block_d(d)), ctx)
+    # B stays in HBM: nothing in the working set grows with n.  The
+    # gather is pipelined over two slots (``repro.kernels.csr_spmm``).
+    return _chunk_footprint(ctx.row_tile, min(512, pallas_block_d(d)), ctx,
+                            slots=2)
+
+
+def _csr_pallas_counters(layout, d: int) -> Dict[str, int]:
+    return pipeline_counts(layout["chunks"], d, pallas_block_d(d))
 
 
 def _csr_pallas_smem(m, ctx: KernelContext) -> int:
@@ -717,7 +731,7 @@ for _f in ("csr", "ell"):
                     "from HBM",
         prepare=_csr_pallas_prepare, run=_csr_pallas_run,
         estimate=_csr_pallas_estimate, vmem_footprint=_csr_pallas_footprint,
-        smem_footprint=_csr_pallas_smem,
+        smem_footprint=_csr_pallas_smem, counters=_csr_pallas_counters,
         layout_key="csr", supported_precisions=_PALLAS_STREAM_PRECISIONS))
 
 
@@ -819,6 +833,7 @@ register(KernelSpec(
     prepare=_csr_pallas_prepare, run=_csr_pallas_run,
     estimate=_ell_coo_estimate("ell_coo_spmm"),
     vmem_footprint=_csr_pallas_footprint, smem_footprint=_csr_pallas_smem,
+    counters=_csr_pallas_counters,
     layout_key="csr", supported_precisions=_PALLAS_STREAM_PRECISIONS))
 
 

@@ -166,6 +166,12 @@ class ShardedPlan(_stream.StreamPlan):
             prec = Precision(prec.value_dtype, "int32")
         return prec
 
+    def _launch_counters(self, d: int) -> dict:
+        """No counters: the shards run jax-backend kernels, whatever the
+        plan's backend, so the plan's kernel counters do not describe
+        them."""
+        return {}
+
     # ------------------------------------------------------------- #
     # Planning: strategy scoring
     # ------------------------------------------------------------- #

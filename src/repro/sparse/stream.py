@@ -148,6 +148,7 @@ class StreamPlan:
         # warm up with one batch, as launch/serve.py does.)
         with obs.span("repro.plan.bind"):
             self._run = self._bind()
+        self._counters: dict = {}        # width -> _launch_counters(width)
         self.executed = 0
         self._reuse_warned = False
 
@@ -160,6 +161,27 @@ class StreamPlan:
         closure here instead of the single-device kernel.
         """
         return self._dispatcher.executor(self._m, self.dispatch)
+
+    def _launch_counters(self, d: int) -> dict:
+        """Static counts of one launch at width ``d``, from the bound
+        kernel's ``KernelSpec.counters`` over its prepared layout."""
+        from repro.kernels import registry
+        spec = registry.get(self.dispatch.chosen, self.dispatch.backend)
+        if spec.counters is None:
+            return {}
+        return spec.counters(self._dispatcher.layout(self._m, self.dispatch),
+                             d)
+
+    def _span_attrs(self, *widths: int) -> dict:
+        """Attrs of a ``repro.execute`` span over launches at ``widths``:
+        the format, and each launch counter summed over the launches."""
+        attrs = {"format": self.chosen}
+        for w in widths:
+            if w not in self._counters:
+                self._counters[w] = self._launch_counters(w)
+            for k, v in self._counters[w].items():
+                attrs[k] = attrs.get(k, 0) + v
+        return attrs
 
     @property
     def n(self) -> int:
@@ -198,10 +220,13 @@ class StreamPlan:
         Returns:
             ``C`` as a dense ``[n, spec.d]`` array.  The call is logged as
             a ``repro.execute`` span (:mod:`repro.obs`) that times the
-            host side: the launch is enqueued, not waited for.
+            host side: the launch is enqueued, not waited for.  Its attrs
+            are the format and the kernel's launch counters
+            (``KernelSpec.counters``; the CSR kernel's ``chunks`` and
+            ``cold_chunks``).
         """
         self._check(b, width=self.spec.d)
-        with obs.span("repro.execute", format=self.chosen):
+        with obs.span("repro.execute", **self._span_attrs(self.spec.d)):
             out = self._run(b)
         self.executed += 1          # count only replays that succeeded
         self._audit_reuse()
@@ -226,7 +251,7 @@ class StreamPlan:
             ``repro.execute`` span times the enqueue only.
         """
         self._check(b, width=self.spec.d)
-        with obs.span("repro.execute", format=self.chosen):
+        with obs.span("repro.execute", **self._span_attrs(self.spec.d)):
             out = self._run(b)
         self.executed += 1
         self._audit_reuse()
@@ -420,7 +445,8 @@ class StreamPlan:
         total = b.shape[1]
         if total == 0:
             return jnp.zeros((self.n, 0), dtype=b.dtype)
-        with obs.span("repro.execute", format=self.chosen):
+        widths = [min(block_d, total - lo) for lo in range(0, total, block_d)]
+        with obs.span("repro.execute", **self._span_attrs(*widths)):
             outs = []
             for lo in range(0, total, block_d):
                 outs.append(self._run(b[:, lo:lo + block_d]))

@@ -238,6 +238,129 @@ def test_csr_kernel_empty_and_ragged_rows():
                                rtol=5e-4, atol=5e-4)
 
 
+# Row-tile structures for the pipelined CSR kernel (row_tile 8, chunk 32,
+# n 128: 16 tiles).  Nonzeros per tile; a tile of k nonzeros owns
+# ceil(k / 32) chunks.
+PIPELINE_TILES = {
+    "tile-chunk-counts": [0, 10, 40, 150, 32, 33, 0, 64, 1, 0, 0, 97, 5,
+                          0, 2, 31],
+    "empty-tiles-at-start": [0] * 6 + [12, 70, 3, 0, 40, 8, 1, 9, 33, 4],
+    "empty-tiles-in-middle": [20, 45, 7] + [0] * 9 + [3, 66, 1, 30],
+    "empty-tiles-at-end": [9, 100, 33, 2, 17] + [0] * 11,
+    "single-chunk": [0] * 7 + [19] + [0] * 8,
+    "all-empty": [0] * 16,
+}
+
+
+def _tiles_matrix(per_tile, seed, n=128, row_tile=8):
+    """COO arrays with ``per_tile[t]`` nonzeros in row tile ``t`` (rows,
+    columns and duplicates drawn at random)."""
+    rng = np.random.default_rng(seed)
+    rows = np.concatenate([
+        t * row_tile + rng.integers(0, row_tile, k)
+        for t, k in enumerate(per_tile)]).astype(np.int64)
+    cols = rng.integers(0, n, rows.shape[0])
+    vals = rng.uniform(0.5, 1.5, rows.shape[0])
+    return rows, cols, vals
+
+
+def _pipelined_csr(structure, precision, d, block_d, interpret=True):
+    """The CSR kernel on ``structure`` against the float64 product of the
+    stored (precision-rounded) values and B; returns the chunk count."""
+    from repro.core.precision import as_precision
+    from repro.kernels.csr_spmm import csr_spmm_pallas, csr_to_row_tiles
+    n, row_tile = 128, 8
+    prec = as_precision(precision)
+    rows, cols, vals = _tiles_matrix(PIPELINE_TILES[structure],
+                                     seed=len(structure), n=n)
+    order = np.lexsort((cols, rows))
+    rows, cols, vals = rows[order], cols[order], vals[order]
+    packed = np.asarray(jnp.asarray(vals, prec.value_jnp))
+    stored = packed.astype(np.float64)
+    indptr = np.concatenate([[0], np.cumsum(np.bincount(rows,
+                                                        minlength=n))])
+    arrays = csr_to_row_tiles(indptr, cols, packed, n=n, row_tile=row_tile,
+                              chunk=32, index_dtype=prec.index_np)
+    b = _b(n, d, prec.value_jnp)
+    out = csr_spmm_pallas(*(jnp.asarray(x) for x in arrays), b, n=n,
+                          row_tile=row_tile, block_d=block_d,
+                          vmem_limit=16 << 20, interpret=interpret)
+    b64 = np.asarray(b, np.float64)
+    a64 = np.zeros((n, n))
+    np.add.at(a64, (rows, cols), stored)
+    bound = np.abs(a64) @ np.abs(b64)
+    err = np.abs(np.asarray(out, np.float64) - a64 @ b64)
+    # fp32 accumulation of exactly stored operands; an output in bf16
+    # (the reduced precisions return B's dtype) rounds once more.
+    eps = np.finfo(np.float32).eps * 64 if prec.value_jnp == jnp.float32 \
+        else 2.0 ** -8
+    assert out.shape == (n, d) and out.dtype == b.dtype
+    assert np.all(err <= eps * bound), float(np.max(err - eps * bound))
+    assert np.all(np.asarray(out)[bound == 0] == 0)
+    return int(arrays[0][-1])
+
+
+@pytest.mark.parametrize("precision", ["f32i32", "bf16i32", "bf16i16"])
+@pytest.mark.parametrize("d,block_d", [(8, 8), (16, 8)],
+                         ids=["one-d-pass", "two-d-passes"])
+@pytest.mark.parametrize("structure", list(PIPELINE_TILES))
+def test_csr_kernel_pipeline_matches_f64_ref(structure, d, block_d,
+                                             precision):
+    """The software-pipelined chunk loop, whose next chunk may sit tiles
+    ahead (or nowhere), against the float64 reference."""
+    chunks = _pipelined_csr(structure, precision, d, block_d)
+    want = sum(-(-k // 32) for k in PIPELINE_TILES[structure])
+    assert chunks == want
+
+
+@pytest.mark.parametrize("mode,structure", [
+    ("on_wait", "tile-chunk-counts"), ("on_wait", "empty-tiles-in-middle"),
+    ("on_wait", "all-empty"), ("eager", "empty-tiles-at-start"),
+    ("eager", "all-empty")])
+def test_csr_kernel_pipeline_waits_on_every_dma(mode, structure, capfd):
+    """Under Pallas's TPU interpreter.  ``on_wait`` lands a DMA only when
+    it is waited on, into scratch that starts as NaN, so a read before its
+    wait, or a gather slot reused while its rows are in flight, shows in
+    the result.  ``eager`` lands each DMA at its start, so one never
+    waited on leaves its semaphore nonzero at kernel exit, which the
+    interpreter reports."""
+    from jax.experimental.pallas import tpu as pltpu
+    _pipelined_csr(structure, "f32i32", 16, 8,
+                   interpret=pltpu.InterpretParams(
+                       dma_execution_mode=mode, uninitialized_memory="nan"))
+    assert "non-zero count" not in capfd.readouterr().out
+
+
+@pytest.mark.parametrize("d", [16, 1024])
+def test_csr_execute_span_counts_chunks(d):
+    """``repro.execute`` carries the CSR kernel's static counts: every
+    d-pass reduces every chunk, and only its first goes cold."""
+    from repro import obs
+    from repro.kernels import registry
+    n = 64
+    m = erdos_renyi(n, 6, seed=5)
+    disp = sparse.Dispatcher(backend="pallas", calibration=False)
+    sp = sparse.StreamPlan(disp, m, sparse.BSpec(d=d, reuse=4),
+                           strategy="csr")
+    chunks = disp.layout(m, sp.dispatch)["chunks"]
+    b = _b(n, d)
+    obs.reset()
+    c = sp.execute(b)
+    sp.execute_wide(_b(n, 3 * d), block_d=d)
+    ex = [s for s in obs.spans() if s.name == "repro.execute"]
+    passes = d // registry.pallas_block_d(d)
+    assert chunks > 0
+    assert ex[0].attrs == {"format": "csr", "chunks": passes * chunks,
+                           "cold_chunks": passes}
+    assert ex[1].attrs == {"format": "csr", "chunks": 3 * passes * chunks,
+                           "cold_chunks": 3 * passes}
+    a = sparse.coo_to_csr(m)
+    np.testing.assert_allclose(
+        np.asarray(c), np.asarray(ref.csr_ref(a.indptr, a.indices, a.data,
+                                              b, n=n)),
+        rtol=5e-4, atol=5e-4)
+
+
 @pytest.mark.parametrize("bandwidth", [1, 5, 17])
 @pytest.mark.parametrize("d", [16, 64])
 def test_banded_kernel_sweep(bandwidth, d):

@@ -74,13 +74,16 @@ def _chunks(index_dtype, value_dtype, num_chunks=NUM_CHUNKS):
     return [packed(index_dtype), packed(jnp.int8), packed(value_dtype)]
 
 
-@pytest.mark.parametrize("value_dtype,index_dtype", [
-    (jnp.float32, jnp.int32), (jnp.bfloat16, jnp.int32),
-    (jnp.bfloat16, jnp.int16)], ids=["f32i32", "bf16i32", "bf16i16"])
-def test_csr_kernel_compiles(one_chip, value_dtype, index_dtype):
+@pytest.mark.parametrize("value_dtype,index_dtype,d", [
+    (jnp.float32, jnp.int32, D), (jnp.bfloat16, jnp.int32, D),
+    (jnp.bfloat16, jnp.int16, D), (jnp.float32, jnp.int32, 2 * D)],
+    ids=["f32i32", "bf16i32", "bf16i16", "f32i32-two-d-passes"])
+def test_csr_kernel_compiles(one_chip, value_dtype, index_dtype, d):
+    """The pipelined gather kernel, also with the pipeline restarting at a
+    second d-pass (``block_d < d``)."""
     num_tiles = N // registry.ROW_TILE
     shapes = ([((num_tiles + 1,), jnp.int32)]
-              + _chunks(index_dtype, value_dtype) + [((N, D), value_dtype)])
+              + _chunks(index_dtype, value_dtype) + [((N, d), value_dtype)])
     _compile(csr_spmm_pallas, shapes, one_chip, n=N,
              row_tile=registry.ROW_TILE, block_d=D, vmem_limit=VMEM_LIMIT,
              interpret=False)
