@@ -16,7 +16,7 @@ from repro.core.sparsity_models import (
 )
 from repro.core.patterns import (
     COOMatrix, banded, block_diagonal, blocked, erdos_renyi, fit_generator,
-    scale_free, serving_suite,
+    hex_mesh, scale_free, serving_suite,
 )
 from repro.core.classify import StructureReport, classify
 
@@ -28,6 +28,6 @@ __all__ = [
     "expected_occupied_columns", "flops_spmm", "hub_edge_fraction",
     "mxu_utilization",
     "COOMatrix", "banded", "block_diagonal", "blocked", "erdos_renyi",
-    "fit_generator", "scale_free", "serving_suite",
+    "fit_generator", "hex_mesh", "scale_free", "serving_suite",
     "StructureReport", "classify",
 ]

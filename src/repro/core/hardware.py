@@ -127,6 +127,42 @@ DEVICE_KINDS = {
 }
 
 
+@dataclasses.dataclass(frozen=True)
+class KernelCosts:
+    """Seconds one step of each Pallas kernel takes on a chip, measured
+    there with the kernel alone (``tools/kernel_costs.py``).
+
+    The roofline says what bytes and FLOPs allow; these say what the
+    kernels issue, which on the chip takes longer.  The dispatcher floors
+    each Pallas candidate's predicted time by them.
+    """
+
+    row_dma_s: float       # CSR family: issuing one packed slot's B-row DMA
+    block_step_s: float    # BCSR: one stored block's grid step
+    row_load_s: float      # binned / rowsplit: one B-row load from VMEM
+
+
+#: ``device_kind`` -> measured kernel costs; a kind missing here plans on
+#: the roofline alone.  TPU v5 lite: one chip, d = 128, float32, the
+#: chip benchmark's structures (a CSR call 0.3257 s for 20.25M slots on
+#: ``lj_powerlaw``, 1.2229 s for 76.55M on ``fem_audikw``; BCSR 0.1008 s
+#: for 284,193 blocks of 64; binned 0.7938 s for 77.10M slots).
+KERNEL_COSTS = {
+    "TPU v5 lite": KernelCosts(row_dma_s=16.0e-9, block_step_s=0.335e-6,
+                               row_load_s=10.3e-9),
+}
+
+
+def kernel_costs(hw: HardwareSpec):
+    """The :class:`KernelCosts` measured on the device ``hw`` describes,
+    or None: a spec that differs from every known device kind's (a
+    replaced field, the host CPU) has no measured costs."""
+    for kind, spec in DEVICE_KINDS.items():
+        if spec == hw:
+            return KERNEL_COSTS.get(kind)
+    return None
+
+
 def for_device_kind(kind: str) -> HardwareSpec:
     """The spec of a device reporting ``device_kind == kind``.
 

@@ -156,6 +156,38 @@ def blocked(n: int, t: int, num_blocks: int, nnz_per_block: float,
                      {"t": t, "num_blocks": N, "D": float(nnz_per_block)})
 
 
+def hex_mesh(nx: int, ny: int, nz: int, dof: int = 3,
+             seed: int = 0) -> COOMatrix:
+    """Stiffness pattern of a 3D solid FEM mesh (3D elasticity).
+
+    Trilinear hexahedra on an ``nx x ny x nz`` grid of nodes, ``dof``
+    displacement unknowns per node.  Nodes are numbered lexicographically
+    (x fastest) with their unknowns interleaved, so row ``dof * node + k``
+    is unknown ``k`` of ``node``.  Each row couples to every unknown of
+    every node that shares an element with its node (27 nodes inside the
+    mesh), so the pattern is symmetric, made of dense ``dof x dof`` node
+    blocks, and holds ``dof**2 * (3nx - 2)(3ny - 2)(3nz - 2)`` nonzeros.
+    """
+    node = np.arange(nx * ny * nz, dtype=np.int64)
+    x, y, z = node % nx, node // nx % ny, node // (nx * ny)
+    rows, cols = [], []
+    for dz in (-1, 0, 1):
+        for dy in (-1, 0, 1):
+            for dx in (-1, 0, 1):
+                ok = ((x + dx >= 0) & (x + dx < nx) & (y + dy >= 0)
+                      & (y + dy < ny) & (z + dz >= 0) & (z + dz < nz))
+                i = node[ok]
+                j = i + dx + nx * (dy + ny * dz)
+                for a in range(dof):
+                    for b in range(dof):
+                        rows.append(dof * i + a)
+                        cols.append(dof * j + b)
+    return _finalize(dof * node.size, np.concatenate(rows),
+                     np.concatenate(cols), "blocked",
+                     np.random.default_rng(seed),
+                     {"nodes": (nx, ny, nz), "dof": dof})
+
+
 def scale_free(n: int, avg_degree: float, alpha: float = 2.2,
                seed: int = 0, k_min: int = 1,
                hub_fraction: float = 0.001) -> COOMatrix:

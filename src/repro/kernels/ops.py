@@ -30,7 +30,7 @@ from repro.kernels.registry import (          # noqa: F401
     grouped_matmul_roofline, pad_empty_block_rows,
 )
 from repro.core.hardware import device_hardware, kernel_vmem_limit
-from repro.kernels.bcsr_spmm import bcsr_spmm_pallas
+from repro.kernels.bcsr_spmm import bcsr_spmm_pallas, pack_blocks
 from repro.kernels.banded_spmm import banded_spmm_pallas
 from repro.kernels.binned_spmm import (
     binned_spmm_pallas, csr_to_slab_bins, pack_rowsplit_chunks,
@@ -76,7 +76,8 @@ def bcsr_spmm(a: BCSRMatrix, b: jnp.ndarray, *, block_d: int = 512,
     """
     _warn_deprecated("bcsr_spmm")
     a = pad_empty_block_rows(a)
-    return bcsr_spmm_pallas(a.blocks, a.block_rows, a.block_cols, b,
+    return bcsr_spmm_pallas(pack_blocks(a.blocks, a.t), a.block_rows,
+                            a.block_cols, b,
                             n=a.n, t=a.t, block_d=block_d,
                             vmem_limit=_vmem_limit(),
                             interpret=_interpret(interpret))

@@ -43,11 +43,13 @@ import numpy as np
 
 from repro.core import sparsity_models as sm
 from repro.core.hardware import (
-    TPU_V5E, HardwareSpec, device_hardware, kernel_vmem_limit)
+    TPU_V5E, HardwareSpec, device_hardware, kernel_smem_limit,
+    kernel_vmem_limit)
 from repro.core.precision import (
     DEFAULT_PRECISION, INT16_MAX_EXTENT, Precision)
 from repro.kernels.banded_spmm import banded_spmm_pallas
-from repro.kernels.bcsr_spmm import bcsr_spmm_pallas
+from repro.kernels.bcsr_spmm import (COORD_BYTES, bcsr_segments,
+                                     bcsr_spmm_pallas, pack_bcsr)
 from repro.kernels.binned_spmm import (
     binned_spmm_pallas, csr_to_slab_bins, pack_rowsplit_chunks,
     rowsplit_spmm_pallas)
@@ -72,6 +74,10 @@ REGISTRY_VERSION: int = 5
 
 #: Rows per output tile of the CSR-family kernels (and per binned visit).
 ROW_TILE: int = 32
+
+#: ``KernelSpec.counters`` keys that describe the layout rather than count
+#: a launch's work: logged once, not summed over launches.
+LAYOUT_COUNTERS = frozenset({"block_t"})
 
 
 def default_interpret() -> bool:
@@ -241,8 +247,9 @@ class KernelSpec:
         lambda m, ctx: 0
     #: Static counts of one launch at width ``d`` from the prepared
     #: layout, ``counters(layout, d) -> {name: int}``, logged as attrs of
-    #: the ``repro.execute`` span (``repro.sparse.stream``); None logs
-    #: none.
+    #: the ``repro.execute`` span (``repro.sparse.stream``) and summed
+    #: over an ``execute_wide``'s launches, except those in
+    #: ``LAYOUT_COUNTERS``; None logs none.
     counters: Optional[Callable[[Any, int], Dict[str, int]]] = None
 
     def supports_precision(self, precision: Precision) -> bool:
@@ -837,17 +844,40 @@ register(KernelSpec(
     layout_key="csr", supported_precisions=_PALLAS_STREAM_PRECISIONS))
 
 
+def bcsr_segment_blocks(hw: HardwareSpec) -> Optional[int]:
+    """Blocks whose coordinates one BCSR segment prefetches into SMEM
+    (``kernel_smem_limit``); None where the spec states no SMEM size."""
+    limit = kernel_smem_limit(hw)
+    return limit // COORD_BYTES if limit else None
+
+
 def _bcsr_pallas_prepare(m, ctx: KernelContext):
-    return pad_empty_block_rows(_convert(ctx, m, "bcsr"))
+    # Packed on the host straight into the kernel's lane-packed layout: a
+    # [N, t, t] device copy beside it would double the largest array.
+    t = ctx.bcsr_block
+    blocks, rows, cols, ptr = pack_bcsr(m.rows, m.cols, m.vals, n=m.n, t=t,
+                                        dtype=ctx.precision.value_jnp)
+    cap = bcsr_segment_blocks(ctx.hardware) or blocks.shape[0]
+    return {"n": m.n, "t": t, "segments": bcsr_segments(ptr, cap),
+            "arrays": tuple(jnp.asarray(x) for x in (blocks, rows, cols))}
 
 
 def _bcsr_pallas_run(layout, b, ctx: KernelContext):
     if ctx.precision.reduced:
         b = b.astype(ctx.precision.value_jnp)
     return bcsr_spmm_pallas(
-        layout.blocks, layout.block_rows, layout.block_cols, b,
-        n=layout.n, t=layout.t, block_d=pallas_block_d(b.shape[1]),
-        vmem_limit=ctx.vmem_limit, interpret=ctx.resolve_interpret())
+        *layout["arrays"], b, n=layout["n"], t=layout["t"],
+        block_d=pallas_block_d(b.shape[1]), vmem_limit=ctx.vmem_limit,
+        interpret=ctx.resolve_interpret(), segments=layout["segments"])
+
+
+def _bcsr_pallas_counters(layout, d: int) -> Dict[str, int]:
+    """Grid steps (stored blocks times d-passes), the block edge, and the
+    ``pallas_call`` segments of one launch."""
+    passes = d // pallas_block_d(d)
+    return {"blocks": passes * int(layout["arrays"][0].shape[0]),
+            "block_t": layout["t"],
+            "segments": len(layout["segments"])}
 
 
 def _bcsr_estimate(m, d, ctx: KernelContext) -> KernelRoofline:
@@ -874,18 +904,26 @@ def _bcsr_pallas_footprint(n: int, d: int, ctx: KernelContext) -> int:
 
 
 def _bcsr_pallas_smem(m, ctx: KernelContext) -> int:
-    from repro.core.classify import block_stats
-    blocks = int(block_stats(m, ctx.bcsr_block)["N"])
-    # Block rows and columns, plus one zero block per empty block row.
-    return 8 * (blocks + -(-m.n // ctx.bcsr_block))
+    """Bytes of the largest segment's coordinates, bounded from above
+    without packing: segments hold at most ``bcsr_segment_blocks`` blocks
+    unless one block row holds more, and a block row holds at most ``n /
+    t`` blocks and at most its nonzeros."""
+    t = ctx.bcsr_block
+    nb = -(-m.n // t)
+    per_row = np.bincount(np.asarray(m.rows) // t, minlength=nb)
+    widest = min(nb, int(per_row.max(initial=1)))
+    blocks = min(m.nnz, nb * nb) + nb           # + one pad per empty row
+    cap = bcsr_segment_blocks(ctx.hardware) or blocks
+    return COORD_BYTES * max(widest, min(blocks, cap))
 
 
 register(KernelSpec(
     format="bcsr", backend="pallas",
-    description="dense-block MXU kernel (scalar-prefetch block walk)",
+    description="dense-block MXU kernel (scalar-prefetch block walk, "
+                "segmented to fit SMEM)",
     prepare=_bcsr_pallas_prepare, run=_bcsr_pallas_run,
     estimate=_bcsr_estimate, vmem_footprint=_bcsr_pallas_footprint,
-    smem_footprint=_bcsr_pallas_smem,
+    smem_footprint=_bcsr_pallas_smem, counters=_bcsr_pallas_counters,
     # Block coordinates are scalar-prefetch metadata, not per-nonzero
     # traffic, so bcsr gains nothing from int16 and keeps int32.
     supported_precisions=_JAX_PRECISIONS))

@@ -51,7 +51,8 @@ from repro.core.precision import (DEFAULT_PRECISION, INT16_MAX_EXTENT,
 from repro.data.dtree import (DecisionTree, DispatchTreeStore,
                               features_from_report)
 from repro.core.hardware import (
-    HardwareSpec, device_hardware, kernel_smem_limit)
+    HardwareSpec, KernelCosts, device_hardware, kernel_costs,
+    kernel_smem_limit)
 from repro.core.roofline import ComputeCeiling
 from repro.core import sparsity_models as sm
 from repro.core.patterns import COOMatrix
@@ -61,6 +62,8 @@ from repro.sparse import formats as fmt
 FORMATS: Tuple[str, ...] = ("csr", "ell", "bcsr", "dia",
                             "binned", "rowsplit", "ell_coo")
 STRATEGIES: Tuple[str, ...] = ("auto",) + FORMATS
+#: Formats whose Pallas picks all run the CSR gather kernel on one layout.
+CSR_FAMILY: Tuple[str, ...] = ("csr", "ell", "ell_coo")
 
 #: Per-format compute ceiling: ``(peak_fraction, d_half)``.  Each
 #: implementation sustains ``peak * peak_fraction * d / (d + d_half)`` on
@@ -358,6 +361,15 @@ class Dispatcher:
                 self._reports[key] = classify(m)
         return self._reports[key]
 
+    def _memo(self, m: COOMatrix, key: tuple, compute: Callable):
+        """``compute()`` once per matrix and ``key``: the layout counts
+        that several candidate rows of one plan share (dropped with
+        ``m``, like every cache here)."""
+        k = (self._track(m), "count") + key
+        if k not in self._converted:
+            self._converted[k] = compute()
+        return self._converted[k]
+
     def convert(self, m: COOMatrix, format: str, precision=None):
         """Convert (and cache) m into ``format``'s container.
 
@@ -507,9 +519,7 @@ class Dispatcher:
                     f"exceeds the {smem_limit / 2 ** 10:.0f} KiB SMEM "
                     f"budget")
         if format == "binned" and m.nnz:
-            slots = kreg.binned_padded_slots(
-                m, slab_rows=ctx.resolve_b_tile(m.n) or m.n,
-                row_tile=ctx.row_tile, chunk=ctx.chunk)
+            slots = self._binned_slots(m, ctx.resolve_b_tile(m.n) or m.n)
             if slots > MAX_PACKED_INFLATION * m.nnz:
                 return (f"binned packing pads its slab visits to "
                         f"{slots / m.nnz:.1f}x the nonzeros (limit "
@@ -630,7 +640,9 @@ class Dispatcher:
             # imports this package for its format containers.)
             from repro.kernels import registry as kreg
             slab = self._binned_slab(n, d, hw, backend)
-            touched, visits = kreg.binned_layout_stats(m, slab_rows=slab)
+            touched, visits = self._memo(
+                m, ("binned_stats", slab),
+                lambda: kreg.binned_layout_stats(m, slab_rows=slab))
             tb = sm.ai_binned(n, nnz, d, slab_rows=slab,
                               slabs_touched=touched, num_visits=visits,
                               row_tile=kreg.ROW_TILE,
@@ -678,10 +690,59 @@ class Dispatcher:
         if flops <= 0 or predicted <= 0:   # empty matrix: nothing to do
             return ai, useful, 0.0, 0.0, conv, ceiling.source
         t_spmm = flops / predicted
+        costs = kernel_costs(hw) if backend == "pallas" else None
+        if costs is not None:
+            # On a chip with measured kernel costs, a launch takes at least
+            # what its kernel issues.
+            t_spmm = max(t_spmm, self._issue_s(m, format, params, d, hw,
+                                               costs, prec))
+            predicted = flops / t_spmm
+            if format in CSR_FAMILY:
+                # The Pallas ell / ell_coo picks pack the CSR layout.
+                conv = nnz * (sv + 2 * si) + (n + 1) * si
         t_conv = 2.0 * conv / hw.hbm_bandwidth          # read COO + write
         amortized = flops / (t_spmm + t_conv / max(reuse, 1))
         return (ai, useful, predicted / 1e9, amortized / 1e9, conv,
                 ceiling.source)
+
+    def _binned_slots(self, m: COOMatrix, slab: int) -> int:
+        """Packed slots of the Pallas binned layout with ``slab``-row B
+        slabs (``registry.binned_padded_slots``), once per matrix."""
+        from repro.kernels import registry as kreg
+        return self._memo(m, ("binned_slots", slab),
+                          lambda: kreg.binned_padded_slots(m, slab_rows=slab))
+
+    def _issue_s(self, m: COOMatrix, format: str, params: dict, d: int,
+                 hw: HardwareSpec, costs: KernelCosts,
+                 prec: Precision) -> float:
+        """Seconds the Pallas kernel for ``format`` takes to issue one
+        launch at width ``d``, counted from the layout the model sizes:
+        packed slots times a row DMA (CSR family), stored blocks times a
+        grid step plus their bytes at HBM bandwidth (BCSR), B-row loads
+        from VMEM (binned, rowsplit).  0 for a kernel with no measured
+        cost (DIA)."""
+        from repro.kernels import registry as kreg
+        passes = d // kreg.pallas_block_d(d)
+        chunk = 128
+        if format in CSR_FAMILY:
+            slots = self._memo(m, ("csr_slots",), lambda: int(
+                (-(-np.bincount(m.rows // kreg.ROW_TILE) // chunk)).sum())
+                * chunk)
+            return passes * slots * costs.row_dma_s
+        if format == "bcsr":
+            t = params["t"]
+            nb = m.n // t
+            empty = nb - np.count_nonzero(np.bincount(m.rows // t,
+                                                      minlength=nb))
+            blocks = params["N"] + empty
+            return passes * blocks * (costs.block_step_s + t * t
+                                      * prec.sizeof_val / hw.hbm_bandwidth)
+        if format == "binned":
+            return passes * self._binned_slots(m, params["slab_rows"]) \
+                * costs.row_load_s
+        if format == "rowsplit":
+            return passes * -(-m.nnz // chunk) * chunk * costs.row_load_s
+        return 0.0
 
     @staticmethod
     def _binned_slab(n: int, d: int, hw: HardwareSpec, backend: str) -> int:

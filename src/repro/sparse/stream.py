@@ -175,12 +175,13 @@ class StreamPlan:
     def _span_attrs(self, *widths: int) -> dict:
         """Attrs of a ``repro.execute`` span over launches at ``widths``:
         the format, and each launch counter summed over the launches."""
+        from repro.kernels.registry import LAYOUT_COUNTERS
         attrs = {"format": self.chosen}
         for w in widths:
             if w not in self._counters:
                 self._counters[w] = self._launch_counters(w)
             for k, v in self._counters[w].items():
-                attrs[k] = attrs.get(k, 0) + v
+                attrs[k] = v if k in LAYOUT_COUNTERS else attrs.get(k, 0) + v
         return attrs
 
     @property
@@ -223,7 +224,8 @@ class StreamPlan:
             host side: the launch is enqueued, not waited for.  Its attrs
             are the format and the kernel's launch counters
             (``KernelSpec.counters``; the CSR kernel's ``chunks`` and
-            ``cold_chunks``).
+            ``cold_chunks``, the BCSR kernel's ``blocks``, ``block_t`` and
+            ``segments``).
         """
         self._check(b, width=self.spec.d)
         with obs.span("repro.execute", **self._span_attrs(self.spec.d)):

@@ -101,6 +101,33 @@ def test_binned_model_wins_high_skew_on_bandwidth_bound_hw():
     assert plan.chosen in GATHER_FAMILY
 
 
+def test_v5e_kernel_costs_pick_bcsr_for_a_mesh_and_csr_for_a_power_law():
+    """On the v5e, whose kernel costs were measured on the chip, the
+    issue floors decide: a 3D elasticity mesh goes to the BCSR kernel
+    (the roofline alone would take the CSR kernel's ``ell`` pick), a
+    power-law graph to ``csr`` (its ``ell_coo`` twin runs the same kernel
+    on the same layout, so it ties and ``csr`` comes first)."""
+    import dataclasses
+    from repro.core import hex_mesh
+    from repro.core.hardware import TPU_V5E, kernel_costs
+
+    def plan(m, hw):
+        disp = sparse.Dispatcher(hardware=hw, backend="pallas",
+                                 calibration=False, tree=False)
+        return disp.plan(m, 128, reuse=1000)
+
+    assert kernel_costs(TPU_V5E) is not None
+    roofline_only = dataclasses.replace(TPU_V5E, name="tpu-v5e-roofline")
+    mesh = hex_mesh(16, 16, 16)
+    picked = plan(mesh, TPU_V5E)
+    assert picked.regime == "blocked" and picked.chosen == "bcsr"
+    assert plan(mesh, roofline_only).chosen != "bcsr"
+    graph = plan(scale_free(2 ** 18, 16, alpha=2.05, seed=10), TPU_V5E)
+    assert graph.regime == "scale_free" and graph.chosen == "csr"
+    assert graph.candidate("ell_coo").amortized_gflops == \
+        graph.candidate("csr").amortized_gflops
+
+
 @pytest.mark.parametrize("structure,d", [("uniform", 8), ("uniform", 64),
                                          ("scale_free", 8),
                                          ("scale_free", 64)])
@@ -111,13 +138,21 @@ def test_reduced_precision_roofline_gain_on_bandwidth_bound_hw(structure, d):
     on the CSR-family kernels for bandwidth-bound structures (uniform /
     scale-free at d >= 8) — halving the bytes-per-nonzero on a
     memory-bound kernel halves its time bound.  (The measured form is
-    soft-reported by benchmarks/run.py's bf16 smoke lane.)"""
-    from repro.core.hardware import TPU_V5E
+    soft-reported by benchmarks/run.py's bf16 smoke lane.)
+
+    The claim is the bytes model's, so the v5e here is the roofline
+    alone: the chip's measured kernel costs (``hardware.KERNEL_COSTS``)
+    floor every CSR-family row at its DMA issue time, the same at every
+    precision, and apply only to the spec of a device kind."""
+    import dataclasses
+    from repro.core.hardware import TPU_V5E, kernel_costs
+    roofline_only = dataclasses.replace(TPU_V5E, name="tpu-v5e-roofline")
+    assert kernel_costs(roofline_only) is None
     if structure == "uniform":
         m = erdos_renyi(8192, 16, seed=11)
     else:
         m = scale_free(8192, 16, alpha=2.05, seed=11)
-    disp = sparse.Dispatcher(hardware=TPU_V5E, backend="pallas",
+    disp = sparse.Dispatcher(hardware=roofline_only, backend="pallas",
                              calibration=False)
     # tolerance admits bf16 (eps 2^-7) so the reduced rows rank eligibly.
     plan = disp.plan(m, d, tolerance=1e-2)

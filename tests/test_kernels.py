@@ -462,3 +462,95 @@ def test_kernels_take_interpret_and_vmem_limit_from_the_caller():
     assert calls == len(fns)
     # Interpret mode is the CPU backend's, never an accelerator's.
     assert registry.KernelContext().resolve_interpret() is True
+
+
+# --------------------------------------------------------------------- #
+# Segmented BCSR: block coordinates cut into runs of block rows that fit
+# the SMEM budget, one pallas_call each, all writing one C.
+# --------------------------------------------------------------------- #
+
+def _small_smem_v5e():
+    """The v5e with SMEM for 48 blocks' coordinates a segment (the
+    kernels may take three quarters of SMEM, 8 bytes a block)."""
+    import dataclasses
+    from repro.core.hardware import TPU_V5E
+    return dataclasses.replace(TPU_V5E, smem_bytes=512)
+
+
+def _segmented_mesh_plan(precision="f32i32", d=16):
+    """A 1,536-row hexahedral mesh forced onto the BCSR kernel (154
+    blocks of 64) with the small SMEM budget."""
+    from repro.core import hex_mesh
+    m = hex_mesh(8, 8, 8, seed=3)
+    disp = sparse.Dispatcher(hardware=_small_smem_v5e(), backend="pallas",
+                             calibration=False, tree=False)
+    sp = sparse.StreamPlan(disp, m, sparse.BSpec(d=d, reuse=4,
+                                                 precision=precision),
+                           strategy="bcsr")
+    return m, sp, disp.layout(m, sp.dispatch)
+
+
+@pytest.mark.parametrize("precision,tol", [
+    # float32 values (rounded once from float64, 2^-24) and B, products
+    # summed in float32 over at most 81 nonzeros a row: each entry within
+    # about (81 + 2) * 2^-24 = 4.9e-6 of |A| @ |B|.
+    ("f32i32", 1e-5),
+    # values, B and the output each rounded to bfloat16 (at most 2^-9
+    # relative), the sums in float32: about 3 * 2^-9 = 5.9e-3 of |A| @ |B|.
+    ("bf16i32", 8e-3)])
+def test_segmented_bcsr_matches_float64(precision, tol):
+    m, sp, layout = _segmented_mesh_plan(precision)
+    assert len(layout["segments"]) >= 3
+    b = _b(m.n, 16)
+    c = np.asarray(sp.execute(b), np.float64)
+    a = np.zeros((m.n, m.n))
+    a[m.rows, m.cols] = m.vals
+    bb = np.asarray(b.astype(jnp.float32), np.float64)
+    scaled = np.abs(c - a @ bb) / (np.abs(a) @ np.abs(bb))
+    assert scaled.max() <= tol
+
+
+def test_bcsr_execute_span_counts_blocks():
+    """``repro.execute`` carries the BCSR kernel's grid steps, block edge
+    and segments; an ``execute_wide`` sums the counts, not the edge."""
+    from repro import obs
+    m, sp, layout = _segmented_mesh_plan(d=8)
+    blocks, segments = layout["arrays"][0].shape[0], len(layout["segments"])
+    assert blocks >= 154 and segments >= 3
+    obs.reset()
+    sp.execute(_b(m.n, 8))
+    sp.execute_wide(_b(m.n, 16), block_d=8)
+    ex = [s for s in obs.spans() if s.name == "repro.execute"]
+    assert ex[0].attrs == {"format": "bcsr", "blocks": blocks, "block_t": 64,
+                           "segments": segments}
+    assert ex[1].attrs == {"format": "bcsr", "blocks": 2 * blocks,
+                           "block_t": 64, "segments": 2 * segments}
+
+
+def test_bcsr_segments_of_an_audikw_sized_operator_fit_v5e_smem():
+    """284,193 blocks over 14,739 block rows (the ``fem_audikw`` operator
+    at t = 64): every segment's coordinates fit the v5e SMEM budget, and
+    the segments cut the block list at block-row boundaries."""
+    from repro.core.hardware import TPU_V5E, kernel_smem_limit
+    from repro.kernels.bcsr_spmm import COORD_BYTES, bcsr_segments
+    from repro.kernels.registry import bcsr_segment_blocks
+    nb, total = 943_296 // 64, 284_193
+    counts = np.full(nb, total // nb)
+    extra = np.random.default_rng(15).choice(nb, total - counts.sum(),
+                                             replace=False)
+    counts[extra] += 1
+    ptr = np.concatenate([[0], np.cumsum(counts)])
+    segments = bcsr_segments(ptr, bcsr_segment_blocks(TPU_V5E))
+    assert len(segments) >= 3
+    assert segments[0][0] == 0 and segments[-1][1] == total
+    assert all(a[1] == b[0] for a, b in zip(segments, segments[1:]))
+    assert {s for seg in segments for s in seg} <= set(ptr.tolist())
+    assert max(COORD_BYTES * (e - s) for s, e in segments) \
+        <= kernel_smem_limit(TPU_V5E)
+
+
+def test_bcsr_segments_refuse_a_block_row_wider_than_a_segment():
+    from repro.kernels.bcsr_spmm import bcsr_segments
+    assert bcsr_segments([0, 2, 5, 6], 3) == ((0, 2), (2, 5), (5, 6))
+    with pytest.raises(ValueError, match="block row 1 holds 3 blocks"):
+        bcsr_segments([0, 2, 5, 6], 2)

@@ -150,3 +150,24 @@ def test_classifier_small_matrix_regimes():
     ]
     for m, expected in cases:
         assert classify(m).regime == expected, (m.pattern, m.n)
+
+
+@pytest.mark.parametrize("nodes", [(2, 2, 2), (4, 3, 5), (6, 6, 6)])
+def test_hex_mesh_counts_symmetry_and_node_blocks(nodes):
+    """Closed-form nonzeros, a symmetric pattern, and dense 3 x 3 blocks
+    for every coupled pair of nodes."""
+    from repro.core import hex_mesh
+    nx, ny, nz = nodes
+    m = hex_mesh(nx, ny, nz)
+    assert m.n == 3 * nx * ny * nz
+    assert m.nnz == 9 * (3 * nx - 2) * (3 * ny - 2) * (3 * nz - 2)
+    pattern = np.zeros((m.n, m.n), dtype=bool)
+    pattern[m.rows, m.cols] = True
+    assert (pattern == pattern.T).all()
+    nodes_coupled = pattern.reshape(m.n // 3, 3, m.n // 3, 3)
+    assert (nodes_coupled.all(axis=(1, 3)) == nodes_coupled.any(
+        axis=(1, 3))).all()
+    # A node inside the mesh couples to the 27 nodes around it.
+    if min(nodes) >= 3:
+        inner = 1 + nx * (1 + ny)
+        assert nodes_coupled[inner].any(axis=(0, 2)).sum() == 27

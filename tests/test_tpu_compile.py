@@ -22,7 +22,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.core.hardware import TPU_V5E, kernel_vmem_limit
 from repro.kernels import registry
 from repro.kernels.banded_spmm import banded_spmm_pallas
-from repro.kernels.bcsr_spmm import bcsr_spmm_pallas
+from repro.kernels.bcsr_spmm import bcsr_segments, bcsr_spmm_pallas, lane_rows
 from repro.kernels.binned_spmm import binned_spmm_pallas, rowsplit_spmm_pallas
 from repro.kernels.csr_spmm import chunks_per_row, csr_spmm_pallas
 
@@ -119,14 +119,39 @@ def test_rowsplit_kernel_compiles(one_chip):
              block_d=D, vmem_limit=VMEM_LIMIT, interpret=False)
 
 
+def _bcsr_shapes(blocks, t, n, d):
+    """Lane-packed blocks (``[N, t/q, q*t]``), coordinates and B."""
+    q = lane_rows(t)
+    return [((blocks, t // q, q * t), jnp.float32), ((blocks,), jnp.int32),
+            ((blocks,), jnp.int32), ((n, d), jnp.float32)]
+
+
 @pytest.mark.parametrize("d", [16, 128, 512])
 def test_bcsr_kernel_compiles(one_chip, d):
     t, blocks = 64, 50_000
-    shapes = [((blocks, t, t), jnp.float32), ((blocks,), jnp.int32),
-              ((blocks,), jnp.int32), ((N, d), jnp.float32)]
-    _compile(bcsr_spmm_pallas, shapes, one_chip, n=N, t=t,
-             block_d=registry.pallas_block_d(d), vmem_limit=VMEM_LIMIT,
-             interpret=False)
+    _compile(bcsr_spmm_pallas, _bcsr_shapes(blocks, t, N, d), one_chip,
+             n=N, t=t, block_d=registry.pallas_block_d(d),
+             vmem_limit=VMEM_LIMIT, interpret=False)
+
+
+def test_segmented_bcsr_kernel_compiles_at_audikw_size(one_chip):
+    """The ``fem_audikw`` operator: 284,193 blocks of 64 at n = 943,296,
+    cut into segments whose coordinates fit SMEM, one ``pallas_call``
+    each into one aliased C: no copy of the 4.66 GB of blocks, no second
+    C."""
+    n, t, total = 943_296, 64, 284_193
+    nb = n // t
+    counts = np.full(nb, total // nb)
+    counts[:total - counts.sum()] += 1
+    segments = bcsr_segments(np.concatenate([[0], np.cumsum(counts)]),
+                             registry.bcsr_segment_blocks(TPU_V5E))
+    assert len(segments) >= 3
+    compiled = _compile(bcsr_spmm_pallas, _bcsr_shapes(total, t, n, D),
+                        one_chip, n=n, t=t, block_d=D,
+                        vmem_limit=VMEM_LIMIT, interpret=False,
+                        segments=segments)
+    assert compiled.as_text().count("tpu_custom_call") >= len(segments)
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * n * D
 
 
 def test_banded_kernel_compiles(one_chip):
