@@ -138,17 +138,19 @@ class KernelCosts:
     """
 
     row_dma_s: float       # CSR family: issuing one packed slot's B-row DMA
-    block_step_s: float    # BCSR: one stored block's grid step
+    block_step_s: float    # BCSR: one stored block's share of a call,
+    #                        after the block's bytes at HBM bandwidth
     row_load_s: float      # binned / rowsplit: one B-row load from VMEM
 
 
 #: ``device_kind`` -> measured kernel costs; a kind missing here plans on
 #: the roofline alone.  TPU v5 lite: one chip, d = 128, float32, the
 #: chip benchmark's structures (a CSR call 0.3257 s for 20.25M slots on
-#: ``lj_powerlaw``, 1.2229 s for 76.55M on ``fem_audikw``; BCSR 0.1008 s
-#: for 284,193 blocks of 64; binned 0.7938 s for 77.10M slots).
+#: ``lj_powerlaw``, 1.2229 s for 76.55M on ``fem_audikw``; the block-row
+#: BCSR kernel 0.0379 s for 284,193 blocks of 64; binned 0.7938 s for
+#: 77.10M slots).
 KERNEL_COSTS = {
-    "TPU v5 lite": KernelCosts(row_dma_s=16.0e-9, block_step_s=0.335e-6,
+    "TPU v5 lite": KernelCosts(row_dma_s=16.0e-9, block_step_s=0.1135e-6,
                                row_load_s=10.3e-9),
 }
 

@@ -76,7 +76,7 @@ def bcsr_spmm(a: BCSRMatrix, b: jnp.ndarray, *, block_d: int = 512,
     """
     _warn_deprecated("bcsr_spmm")
     a = pad_empty_block_rows(a)
-    return bcsr_spmm_pallas(pack_blocks(a.blocks, a.t), a.block_rows,
+    return bcsr_spmm_pallas(pack_blocks(a.blocks, a.t), a.block_ptr,
                             a.block_cols, b,
                             n=a.n, t=a.t, block_d=block_d,
                             vmem_limit=_vmem_limit(),

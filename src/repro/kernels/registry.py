@@ -49,7 +49,8 @@ from repro.core.precision import (
     DEFAULT_PRECISION, INT16_MAX_EXTENT, Precision)
 from repro.kernels.banded_spmm import banded_spmm_pallas
 from repro.kernels.bcsr_spmm import (COORD_BYTES, bcsr_segments,
-                                     bcsr_spmm_pallas, pack_bcsr)
+                                     bcsr_spmm_pallas, d_passes, pack_bcsr,
+                                     ring_counts, ring_slots, segment_rows)
 from repro.kernels.binned_spmm import (
     binned_spmm_pallas, csr_to_slab_bins, pack_rowsplit_chunks,
     rowsplit_spmm_pallas)
@@ -855,11 +856,14 @@ def _bcsr_pallas_prepare(m, ctx: KernelContext):
     # Packed on the host straight into the kernel's lane-packed layout: a
     # [N, t, t] device copy beside it would double the largest array.
     t = ctx.bcsr_block
-    blocks, rows, cols, ptr = pack_bcsr(m.rows, m.cols, m.vals, n=m.n, t=t,
-                                        dtype=ctx.precision.value_jnp)
+    blocks, _, cols, ptr = pack_bcsr(m.rows, m.cols, m.vals, n=m.n, t=t,
+                                     dtype=ctx.precision.value_jnp)
     cap = bcsr_segment_blocks(ctx.hardware) or blocks.shape[0]
-    return {"n": m.n, "t": t, "segments": bcsr_segments(ptr, cap),
-            "arrays": tuple(jnp.asarray(x) for x in (blocks, rows, cols))}
+    segments = bcsr_segments(ptr, cap)
+    rows = segment_rows(ptr, segments)
+    return {"n": m.n, "t": t, "segments": segments, "rows": rows,
+            "counts": ring_counts(ptr, rows, t),
+            "arrays": tuple(jnp.asarray(x) for x in (blocks, ptr, cols))}
 
 
 def _bcsr_pallas_run(layout, b, ctx: KernelContext):
@@ -868,14 +872,16 @@ def _bcsr_pallas_run(layout, b, ctx: KernelContext):
     return bcsr_spmm_pallas(
         *layout["arrays"], b, n=layout["n"], t=layout["t"],
         block_d=pallas_block_d(b.shape[1]), vmem_limit=ctx.vmem_limit,
-        interpret=ctx.resolve_interpret(), segments=layout["segments"])
+        interpret=ctx.resolve_interpret(), segments=layout["segments"],
+        rows=layout["rows"])
 
 
 def _bcsr_pallas_counters(layout, d: int) -> Dict[str, int]:
-    """Grid steps (stored blocks times d-passes), the block edge, and the
-    ``pallas_call`` segments of one launch."""
-    passes = d // pallas_block_d(d)
-    return {"blocks": passes * int(layout["arrays"][0].shape[0]),
+    """Stored blocks visited, grid steps (block rows) and cold blocks of
+    a d-pass (``ring_counts``) times the d-passes; the block edge, and
+    the ``pallas_call`` segments of one launch."""
+    width, bd = d_passes(d, pallas_block_d(d))
+    return {**{k: width // bd * v for k, v in layout["counts"].items()},
             "block_t": layout["t"],
             "segments": len(layout["segments"])}
 
@@ -900,7 +906,12 @@ def _block_footprint(t: int, bd: int, ctx: KernelContext) -> int:
 
 
 def _bcsr_pallas_footprint(n: int, d: int, ctx: KernelContext) -> int:
-    return _block_footprint(ctx.bcsr_block, min(512, pallas_block_d(d)), ctx)
+    """The ring's slots of a ``t x t`` block and a ``t x bd`` B tile at
+    the value width, and the fp32 ``t x bd`` C tile the blocks accumulate
+    into, double-buffered by the output pipeline."""
+    t, (_, bd) = ctx.bcsr_block, d_passes(d, pallas_block_d(d))
+    return (ring_slots(t) * ctx.precision.sizeof_val * (t * t + t * bd)
+            + 2 * 4 * t * bd)
 
 
 def _bcsr_pallas_smem(m, ctx: KernelContext) -> int:
@@ -919,8 +930,9 @@ def _bcsr_pallas_smem(m, ctx: KernelContext) -> int:
 
 register(KernelSpec(
     format="bcsr", backend="pallas",
-    description="dense-block MXU kernel (scalar-prefetch block walk, "
-                "segmented to fit SMEM)",
+    description="dense-block MXU kernel (a grid step per block row, "
+                "blocks streamed through a DMA ring, segmented to fit "
+                "SMEM)",
     prepare=_bcsr_pallas_prepare, run=_bcsr_pallas_run,
     estimate=_bcsr_estimate, vmem_footprint=_bcsr_pallas_footprint,
     smem_footprint=_bcsr_pallas_smem, counters=_bcsr_pallas_counters,

@@ -718,9 +718,9 @@ class Dispatcher:
         """Seconds the Pallas kernel for ``format`` takes to issue one
         launch at width ``d``, counted from the layout the model sizes:
         packed slots times a row DMA (CSR family), stored blocks times a
-        grid step plus their bytes at HBM bandwidth (BCSR), B-row loads
-        from VMEM (binned, rowsplit).  0 for a kernel with no measured
-        cost (DIA)."""
+        block's share of the block-row kernel plus the block's bytes at
+        HBM bandwidth (BCSR), B-row loads from VMEM (binned, rowsplit).
+        0 for a kernel with no measured cost (DIA)."""
         from repro.kernels import registry as kreg
         passes = d // kreg.pallas_block_d(d)
         chunk = 128

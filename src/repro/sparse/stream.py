@@ -224,8 +224,8 @@ class StreamPlan:
             host side: the launch is enqueued, not waited for.  Its attrs
             are the format and the kernel's launch counters
             (``KernelSpec.counters``; the CSR kernel's ``chunks`` and
-            ``cold_chunks``, the BCSR kernel's ``blocks``, ``block_t`` and
-            ``segments``).
+            ``cold_chunks``, the BCSR kernel's ``blocks``, ``steps``,
+            ``cold_blocks``, ``block_t`` and ``segments``).
         """
         self._check(b, width=self.spec.d)
         with obs.span("repro.execute", **self._span_attrs(self.spec.d)):

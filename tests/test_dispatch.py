@@ -128,6 +128,34 @@ def test_v5e_kernel_costs_pick_bcsr_for_a_mesh_and_csr_for_a_power_law():
         graph.candidate("csr").amortized_gflops
 
 
+#: The v5e's BCSR cost: seconds of the block-row kernel a stored block,
+#: after the block's bytes (``tools/kernel_costs.py`` on ``fem_audikw``).
+BCSR_BLOCK_S = 0.1135e-6
+
+
+def test_v5e_bcsr_prediction_is_stored_blocks_times_the_block_cost():
+    """The v5e plan predicts the BCSR kernel on a mesh from its measured
+    cost: stored blocks times (``block_step_s``, a stored block's share
+    of the block-row kernel's time, plus the block's bytes at HBM
+    bandwidth)."""
+    from repro.core import hex_mesh
+    from repro.core.hardware import KERNEL_COSTS, TPU_V5E
+    from repro.kernels.bcsr_spmm import pack_bcsr
+    costs = KERNEL_COSTS["TPU v5 lite"]
+    assert costs.block_step_s == pytest.approx(BCSR_BLOCK_S)
+    mesh = hex_mesh(16, 16, 16)
+    d, t = 128, 64
+    disp = sparse.Dispatcher(hardware=TPU_V5E, backend="pallas",
+                             calibration=False, tree=False)
+    c = disp.plan(mesh, d, reuse=1000).candidate("bcsr")
+    blocks = pack_bcsr(mesh.rows, mesh.cols, mesh.vals, n=mesh.n, t=t,
+                       dtype=np.float32)[0].shape[0]
+    predicted_s = 2.0 * mesh.nnz * d / (c.predicted_gflops * 1e9)
+    assert predicted_s == pytest.approx(
+        blocks * (costs.block_step_s + t * t * 4 / TPU_V5E.hbm_bandwidth),
+        rel=1e-9)
+
+
 @pytest.mark.parametrize("structure,d", [("uniform", 8), ("uniform", 64),
                                          ("scale_free", 8),
                                          ("scale_free", 64)])

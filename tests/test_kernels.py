@@ -53,6 +53,185 @@ def test_bcsr_kernel_empty_rows_padded():
                                rtol=5e-4, atol=5e-4)
 
 
+# --------------------------------------------------------------------- #
+# The block-row BCSR kernel: one grid step per block row, the row's blocks
+# streamed through a ring of RING slots that runs across block rows.
+# --------------------------------------------------------------------- #
+
+#: Stored blocks in each block row (a 0 is padded with a zero diagonal
+#: block by ``pack_bcsr``) and the blocks a segment may hold (None: one).
+#: At t = 16 the kernel multiplies 8 blocks at a time, then one at a
+#: time, through a ring of 32: rows of fewer, exactly 8, groups of 8 and
+#: a remainder, a full ring and more than it holds.
+RAGGED = [1, 7, 3, 35, 8, 2, 17, 0, 32, 4, 33] + [1] * 25
+BLOCK_ROWS = {
+    "ragged": (RAGGED, None),
+    "rows-of-one-block": ([1] * 8, None),
+    "padded-empty-rows": ([0, 3, 0, 6, 0, 0, 2, 0], None),
+    "segments": (RAGGED, 60),
+    "segments-of-one-row": ([2, 5, 0, 6, 1, 0], 6),
+}
+
+
+def _block_row_matrix(per_row, t, seed):
+    """COO arrays with ``per_row[r]`` distinct t x t blocks in block row
+    ``r``, each holding ``2 t`` random nonzeros."""
+    rng = np.random.default_rng(seed)
+    nb = len(per_row)
+    rows, cols = [], []
+    for r, k in enumerate(per_row):
+        for c in rng.choice(nb, k, replace=False):
+            rows.append(r * t + rng.integers(0, t, 2 * t))
+            cols.append(c * t + rng.integers(0, t, 2 * t))
+    rows = np.concatenate(rows or [np.zeros(0, np.int64)])
+    cols = np.concatenate(cols or [np.zeros(0, np.int64)])
+    lin = np.unique(rows * nb * t + cols)
+    return lin // (nb * t), lin % (nb * t), rng.uniform(0.5, 1.5, lin.size)
+
+
+def _ring_bcsr(structure, precision, d, block_d, interpret=True, t=16):
+    """The BCSR kernel on ``structure`` against the float64 product of the
+    stored (precision-rounded) values and B; returns the packed
+    ``(block_ptr, segments, rows)``."""
+    from repro.core.precision import as_precision
+    from repro.kernels.bcsr_spmm import (bcsr_segments, bcsr_spmm_pallas,
+                                         pack_bcsr, segment_rows)
+    per_row, cap = BLOCK_ROWS[structure]
+    n = len(per_row) * t
+    prec = as_precision(precision)
+    rows, cols, vals = _block_row_matrix(per_row, t, seed=len(structure))
+    stored = np.asarray(jnp.asarray(vals, prec.value_jnp)).astype(np.float64)
+    blocks, _, bcols, ptr = pack_bcsr(rows, cols, vals, n=n, t=t,
+                                      dtype=prec.value_jnp)
+    segments = bcsr_segments(ptr, cap or blocks.shape[0])
+    seg_rows = segment_rows(ptr, segments)
+    b = _b(n, d, prec.value_jnp)
+    out = bcsr_spmm_pallas(jnp.asarray(blocks), jnp.asarray(ptr),
+                           jnp.asarray(bcols), b, n=n, t=t, block_d=block_d,
+                           vmem_limit=16 << 20, interpret=interpret,
+                           segments=segments, rows=seg_rows)
+    b64 = np.asarray(b, np.float64)
+    a64 = np.zeros((n, n))
+    np.add.at(a64, (rows, cols), stored)
+    bound = np.abs(a64) @ np.abs(b64)
+    err = np.abs(np.asarray(out, np.float64) - a64 @ b64)
+    # fp32 accumulation of exactly stored operands; an output in bf16
+    # (the reduced precisions return B's dtype) rounds once more.
+    eps = np.finfo(np.float32).eps * 64 if prec.value_jnp == jnp.float32 \
+        else 2.0 ** -8
+    assert out.shape == (n, d) and out.dtype == b.dtype
+    assert np.all(err <= eps * bound), float(np.max(err - eps * bound))
+    assert np.all(np.asarray(out)[bound == 0] == 0)
+    return ptr, segments, seg_rows
+
+
+#: ``(d, block_d)``: one d-pass, and two (B padded to 128-lane passes).
+D_PASSES = {"one-d-pass": (8, 8), "two-d-passes": (256, 128)}
+RING_CASES = [(s, p, "f32i32") for p in D_PASSES for s in BLOCK_ROWS] + [
+    ("ragged", "two-d-passes", "bf16i32"),
+    ("segments", "one-d-pass", "bf16i32")]
+
+
+@pytest.mark.parametrize("structure,passes,precision", RING_CASES,
+                         ids=["-".join(c) for c in RING_CASES])
+def test_bcsr_kernel_ring_matches_f64_ref(structure, passes, precision):
+    """Block rows of 1 block to more than the ring holds, padded empty
+    rows and several segments, against the float64 reference; the
+    segments cover every block row once."""
+    from repro.kernels.bcsr_spmm import blocks_per_matmul, ring_slots
+    assert blocks_per_matmul(16) in RAGGED
+    assert max(max(p) for p, _ in BLOCK_ROWS.values()) > ring_slots(16)
+    d, block_d = D_PASSES[passes]
+    per_row, cap = BLOCK_ROWS[structure]
+    ptr, segments, rows = _ring_bcsr(structure, precision, d, block_d)
+    assert len(segments) > 1 if cap else len(segments) == 1
+    assert rows[0][0] == 0 and rows[-1][1] == len(per_row)
+    assert all(a[1] == b[0] for a, b in zip(rows, rows[1:]))
+    assert [(int(ptr[a]), int(ptr[b])) for a, b in rows] == list(segments)
+
+
+@pytest.mark.parametrize("mode,structure", [
+    ("on_wait", "ragged"), ("on_wait", "segments"),
+    ("eager", "padded-empty-rows"), ("eager", "segments-of-one-row")])
+def test_bcsr_kernel_ring_waits_on_every_dma(mode, structure, capfd):
+    """Under Pallas's TPU interpreter, as for the CSR kernel: ``on_wait``
+    lands a DMA only when it is waited on, into scratch that starts as
+    NaN, so a block read before its wait or a slot reused while its DMAs
+    are in flight shows in the result; ``eager`` reports a DMA never
+    waited on."""
+    from jax.experimental.pallas import tpu as pltpu
+    _ring_bcsr(structure, "f32i32", 256, 128,
+               interpret=pltpu.InterpretParams(
+                   dma_execution_mode=mode, uninitialized_memory="nan"))
+    assert "non-zero count" not in capfd.readouterr().out
+
+
+def test_bcsr_kernel_zeroes_block_rows_it_holds_no_block_for():
+    """A block-row pointer with empty rows (no padding): their C tiles
+    come out zero, at either end of a segment and inside one."""
+    from repro.kernels.bcsr_spmm import (bcsr_segments, bcsr_spmm_pallas,
+                                         pack_bcsr, segment_rows)
+    t, per_row = 16, [0, 3, 0, 0, 5, 0, 2, 0]
+    n = len(per_row) * t
+    rows, cols, vals = _block_row_matrix(per_row, t, seed=4)
+    blocks, brows, bcols, _ = pack_bcsr(rows, cols, vals, n=n, t=t,
+                                        dtype=jnp.float32)
+    keep = np.asarray(per_row)[brows] > 0          # drop the padding
+    ptr = np.concatenate([[0], np.cumsum(np.bincount(
+        brows[keep], minlength=len(per_row)))]).astype(np.int32)
+    segments = bcsr_segments(ptr, 8)
+    assert segments == ((0, 8), (8, 10))
+    assert segment_rows(ptr, segments) == ((0, 5), (5, 8))
+    b = _b(n, 8)
+    out = bcsr_spmm_pallas(jnp.asarray(blocks[keep]), jnp.asarray(ptr),
+                           jnp.asarray(bcols[keep]), b, n=n, t=t,
+                           block_d=8, vmem_limit=16 << 20, interpret=True,
+                           segments=segments,
+                           rows=segment_rows(ptr, segments))
+    a = np.zeros((n, n))
+    np.add.at(a, (rows, cols), vals.astype(np.float32))
+    np.testing.assert_allclose(np.asarray(out), a @ np.asarray(b),
+                               rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("d,block_d,passes", [(8, 8, 1), (128, 128, 1),
+                                              (1024, 512, 2),
+                                              (192, 64, 2)])
+def test_bcsr_ring_counts(d, block_d, passes):
+    """Blocks, grid steps (block rows) and cold blocks of one d-pass: a
+    segment's first matmul goes cold, ``blocks_per_matmul(t)`` blocks, or
+    one where the first row that holds blocks has fewer (the rest are
+    hidden behind it).  A d-tile narrower than 128 lanes runs as whole
+    128-lane passes."""
+    from repro.kernels.bcsr_spmm import d_passes, ring_counts
+    ptr = np.cumsum([0, 5, 0, 2, 6])
+    assert ring_counts(ptr, ((0, 2), (2, 4)), t=64) == {
+        "blocks": 13, "steps": 4, "cold_blocks": 4 + 1}
+    assert ring_counts(ptr, ((0, 1), (1, 3), (3, 4)),
+                       t=64)["cold_blocks"] == 4 + 1 + 4
+    assert ring_counts(ptr, ((0, 4),), t=128)["cold_blocks"] == 2
+    width, bd = d_passes(d, block_d)
+    assert width // bd == passes
+
+
+@pytest.mark.parametrize("t", [16, 32, 64, 128])
+def test_bcsr_vmem_footprint_is_the_ring(t):
+    """The VMEM gate counts the ring's slots of an A block and a B tile
+    (room for the blocks one matmul takes and as many in flight) and the
+    double-buffered fp32 C tile; at bd = 512 it fits the v5e's kernel
+    budget at every block edge."""
+    from repro.core.hardware import TPU_V5E
+    from repro.kernels import registry
+    from repro.kernels.bcsr_spmm import blocks_per_matmul, ring_slots
+    ctx = registry.KernelContext(hardware=TPU_V5E, bcsr_block=t)
+    slots, bd = ring_slots(t), 512
+    assert slots >= 2 * blocks_per_matmul(t)
+    footprint = registry.get("bcsr", "pallas").vmem_footprint(1 << 20, 1024,
+                                                              ctx)
+    assert footprint == slots * 4 * (t * t + t * bd) + 2 * 4 * t * bd
+    assert footprint <= ctx.vmem_limit
+
+
 @pytest.mark.parametrize("pattern", ["er", "banded", "blocked", "powerlaw"])
 @pytest.mark.parametrize("d,block_d", [(8, 8), (64, 32)])
 def test_csr_kernel_sweep(pattern, d, block_d):
@@ -511,19 +690,29 @@ def test_segmented_bcsr_matches_float64(precision, tol):
 
 
 def test_bcsr_execute_span_counts_blocks():
-    """``repro.execute`` carries the BCSR kernel's grid steps, block edge
-    and segments; an ``execute_wide`` sums the counts, not the edge."""
+    """``repro.execute`` carries the BCSR kernel's stored blocks, grid
+    steps (one a block row), cold blocks (a segment's first matmul),
+    block edge and segments; an ``execute_wide`` sums the counts, not
+    the edge."""
     from repro import obs
+    from repro.kernels.bcsr_spmm import blocks_per_matmul
     m, sp, layout = _segmented_mesh_plan(d=8)
     blocks, segments = layout["arrays"][0].shape[0], len(layout["segments"])
+    steps = m.n // 64
+    ptr = np.asarray(layout["arrays"][1])
     assert blocks >= 154 and segments >= 3
+    # Each segment's first row fills a matmul: it goes cold, no more.
+    assert all(ptr[r + 1] - ptr[r] >= 4 for r, _ in layout["rows"])
+    cold = blocks_per_matmul(64) * segments
     obs.reset()
     sp.execute(_b(m.n, 8))
     sp.execute_wide(_b(m.n, 16), block_d=8)
     ex = [s for s in obs.spans() if s.name == "repro.execute"]
-    assert ex[0].attrs == {"format": "bcsr", "blocks": blocks, "block_t": 64,
-                           "segments": segments}
+    assert ex[0].attrs == {"format": "bcsr", "blocks": blocks,
+                           "steps": steps, "cold_blocks": cold,
+                           "block_t": 64, "segments": segments}
     assert ex[1].attrs == {"format": "bcsr", "blocks": 2 * blocks,
+                           "steps": 2 * steps, "cold_blocks": 2 * cold,
                            "block_t": 64, "segments": 2 * segments}
 
 

@@ -22,7 +22,8 @@ from jax.sharding import SingleDeviceSharding
 from repro.core.hardware import TPU_V5E, kernel_vmem_limit
 from repro.kernels import registry
 from repro.kernels.banded_spmm import banded_spmm_pallas
-from repro.kernels.bcsr_spmm import bcsr_segments, bcsr_spmm_pallas, lane_rows
+from repro.kernels.bcsr_spmm import (bcsr_segments, bcsr_spmm_pallas,
+                                     lane_rows, segment_rows)
 from repro.kernels.binned_spmm import binned_spmm_pallas, rowsplit_spmm_pallas
 from repro.kernels.csr_spmm import chunks_per_row, csr_spmm_pallas
 
@@ -120,9 +121,10 @@ def test_rowsplit_kernel_compiles(one_chip):
 
 
 def _bcsr_shapes(blocks, t, n, d):
-    """Lane-packed blocks (``[N, t/q, q*t]``), coordinates and B."""
+    """Lane-packed blocks (``[N, t/q, q*t]``), the block-row pointer, the
+    block columns and B."""
     q = lane_rows(t)
-    return [((blocks, t // q, q * t), jnp.float32), ((blocks,), jnp.int32),
+    return [((blocks, t // q, q * t), jnp.float32), ((n // t + 1,), jnp.int32),
             ((blocks,), jnp.int32), ((n, d), jnp.float32)]
 
 
@@ -143,13 +145,13 @@ def test_segmented_bcsr_kernel_compiles_at_audikw_size(one_chip):
     nb = n // t
     counts = np.full(nb, total // nb)
     counts[:total - counts.sum()] += 1
-    segments = bcsr_segments(np.concatenate([[0], np.cumsum(counts)]),
-                             registry.bcsr_segment_blocks(TPU_V5E))
+    ptr = np.concatenate([[0], np.cumsum(counts)])
+    segments = bcsr_segments(ptr, registry.bcsr_segment_blocks(TPU_V5E))
     assert len(segments) >= 3
     compiled = _compile(bcsr_spmm_pallas, _bcsr_shapes(total, t, n, D),
                         one_chip, n=n, t=t, block_d=D,
                         vmem_limit=VMEM_LIMIT, interpret=False,
-                        segments=segments)
+                        segments=segments, rows=segment_rows(ptr, segments))
     assert compiled.as_text().count("tpu_custom_call") >= len(segments)
     assert compiled.memory_analysis().temp_size_in_bytes < 4 * n * D
 

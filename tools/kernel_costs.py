@@ -1,14 +1,15 @@
 """Measure what one step of each Pallas SpMM kernel costs on the chip.
 
     python tools/kernel_costs.py [--configs lj_powerlaw fem_audikw]
+                                 [--formats bcsr ...]
 
 For each configuration of the chip benchmark (its operator, values from
 seed 1, d = 128, float32) the default dispatcher plans ``auto`` once; then
-every Pallas format that plan finds eligible runs alone, forced, on one
-device-made B: one warm-up call, then the median of ``--calls`` calls, each
-waited for.  Each line of output gives the format, its call time, what the
-plan predicted for it, and the steps its layout issues, over which the
-call time is divided:
+every Pallas format that plan finds eligible (or those of ``--formats``)
+runs alone, forced, on one device-made B: one warm-up call, then the
+median of ``--calls`` calls, each waited for.  Each line of output gives
+the format, its call time, what the plan predicted for it, and the steps
+its layout issues, over which the call time is divided:
 
 * ``csr`` / ``ell`` / ``ell_coo`` (one kernel, one layout): packed slots,
   one B-row DMA each -> ``KernelCosts.row_dma_s``;
@@ -55,7 +56,7 @@ def _time(run, b, calls: int) -> float:
     return statistics.median(times)
 
 
-def measure(name: str, calls: int) -> list:
+def measure(name: str, calls: int, formats=None) -> list:
     import jax
     import jax.numpy as jnp
     from yard import names, operator
@@ -80,7 +81,8 @@ def measure(name: str, calls: int) -> list:
     flops = 2.0 * m.nnz * D
     out, layouts = [], {}
     for c in plan.candidates:
-        if not c.eligible or c.precision != "f32i32":
+        if not c.eligible or c.precision != "f32i32" or (
+                formats and c.format not in formats):
             continue
         spec = registry.get(c.format, "pallas")
         ctx = registry.KernelContext(plan_d=D)
@@ -110,13 +112,15 @@ def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--configs", nargs="+",
                     default=["lj_powerlaw", "fem_audikw"])
+    ap.add_argument("--formats", nargs="+",
+                    help="time only these formats (default: every eligible)")
     ap.add_argument("--calls", type=int, default=5)
     args = ap.parse_args()
     import jax
     if jax.devices()[0].platform != "tpu":
         print("needs a TPU", file=sys.stderr)
         return 1
-    rows = [r for name in args.configs for r in measure(name, args.calls)]
+    rows = [r for name in args.configs for r in measure(name, args.calls, args.formats)]
     dest = ROOT / "chiprun_out"
     dest.mkdir(exist_ok=True)
     (dest / "kernel_costs.json").write_text(json.dumps(rows, indent=1))
