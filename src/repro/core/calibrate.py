@@ -239,20 +239,20 @@ class CalibrationStore:
             payload = json.loads(path.read_text(encoding="utf-8"))
         except (OSError, json.JSONDecodeError):
             return (f"calibration file {path.name} is unreadable; using "
-                    f"default ceilings (re-run benchmarks/run.py "
-                    f"--calibrate)")
+                    f"default ceilings (re-run python -m "
+                    f"repro.launch.serve --calibrate)")
         if payload.get("fingerprint") != hw.fingerprint():
             return (f"calibration {path.name} was fitted for fingerprint "
                     f"{payload.get('fingerprint')}, active spec is "
                     f"{hw.fingerprint()}; using default ceilings (re-run "
-                    f"benchmarks/run.py --calibrate)")
+                    f"python -m repro.launch.serve --calibrate)")
         from repro.kernels import registry
         stored = int(payload.get("registry_version", 0))
         if stored < registry.REGISTRY_VERSION:
             return (f"calibration {path.name} predates kernel registry "
                     f"v{registry.REGISTRY_VERSION} (fitted at "
                     f"v{stored}); ceilings may describe retired kernels "
-                    f"(re-run benchmarks/run.py --calibrate)")
+                    f"(re-run python -m repro.launch.serve --calibrate)")
         return None
 
 

@@ -58,9 +58,8 @@ class HardwareSpec:
 
         Keys persisted kernel calibrations (``repro.core.calibrate``): a
         calibration fitted on one device must not be applied to another.
-        Bandwidth fields are deliberately excluded — ``hbm_bandwidth`` is
-        routinely replaced by the run-time STREAM measurement
-        (``benchmarks/spmm_suite.make_dispatcher``), and the fitted
+        Bandwidth fields are deliberately excluded — a caller may put a
+        measured ``hbm_bandwidth`` in a spec's place, and the fitted
         ``(peak_fraction, d_half)`` ceilings describe the compute side
         of the roofline, which that substitution does not change.  The
         interconnect fields (``ici_bytes_per_s``,
@@ -103,12 +102,12 @@ TPU_V5E = HardwareSpec(
     collective_latency_s=1e-6,
 )
 
-# Host CPU of this container (used only for wall-clock benchmark *context*;
-# beta is measured at runtime by benchmarks/stream.py, mirroring the paper).
+# The host CPU (the CPU backend's spec; its bandwidth is a placeholder,
+# not a measurement).
 HOST_CPU = HardwareSpec(
     name="container-host-cpu",
     peak_flops=50e9,
-    hbm_bandwidth=10e9,               # placeholder; STREAM overrides at runtime
+    hbm_bandwidth=10e9,               # placeholder
     link_bandwidth=0.0,
     vmem_bytes=32 * 2**20,
     hbm_bytes=35 * 2**30,

@@ -347,10 +347,8 @@ def block_diagonal(n: int, t: int = 64, seed: int = 0) -> COOMatrix:
 def serving_suite(n: int):
     """The four paper structures at serving scale (generator thunks).
 
-    The single registry shared by the streamed-dispatch surfaces —
-    ``repro.launch.serve --spmm-stream`` and ``benchmarks/stream.py`` —
-    so the serving demo and the CI-gated suite measure the same
-    operators.
+    The registry behind ``repro.launch.serve --spmm-stream`` and
+    ``--engine``, so both serving demos run the same operators.
     """
     return {
         "moe-block": lambda: block_diagonal(n, 64, seed=0),

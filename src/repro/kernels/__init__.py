@@ -2,27 +2,21 @@
 
 ``repro.kernels.registry`` is the uniform entry point: one ``KernelSpec``
 (layout prep, launch, roofline estimate, VMEM footprint) per
-``(format, backend)`` pair.  The container-level wrappers below are kept
-for direct kernel use.
+``(format, backend)`` pair.  A caller that packs its own layout calls the
+``*_pallas`` kernel in its module directly.
 """
 from repro.kernels import registry
-from repro.kernels.ops import (
-    band_to_blocks, banded_spmm, bcsr_kernel_roofline, bcsr_spmm,
-    binned_spmm, csr_kernel_roofline, csr_spmm, dia_kernel_roofline,
-    grouped_matmul, grouped_matmul_roofline, pad_empty_block_rows,
-    rowsplit_spmm,
-)
 from repro.kernels.registry import (
-    KernelContext, KernelRoofline, KernelSpec, choose_b_tile,
-    feature_matrix, formats_for,
+    KernelContext, KernelRoofline, KernelSpec, band_to_blocks,
+    bcsr_kernel_roofline, choose_b_tile, csr_kernel_roofline,
+    dia_kernel_roofline, feature_matrix, formats_for,
+    grouped_matmul_roofline, pad_empty_block_rows,
 )
 
 __all__ = [
     "registry",
-    "band_to_blocks", "banded_spmm", "bcsr_kernel_roofline", "bcsr_spmm",
-    "binned_spmm", "csr_kernel_roofline", "csr_spmm",
-    "dia_kernel_roofline", "grouped_matmul", "grouped_matmul_roofline",
-    "pad_empty_block_rows", "rowsplit_spmm",
-    "KernelContext", "KernelRoofline", "KernelSpec", "choose_b_tile",
-    "feature_matrix", "formats_for",
+    "KernelContext", "KernelRoofline", "KernelSpec", "band_to_blocks",
+    "bcsr_kernel_roofline", "choose_b_tile", "csr_kernel_roofline",
+    "dia_kernel_roofline", "feature_matrix", "formats_for",
+    "grouped_matmul_roofline", "pad_empty_block_rows",
 ]

@@ -1,9 +1,7 @@
 """Kernel registry: every SpMM kernel behind one ``KernelSpec`` interface.
 
-The dispatcher used to hard-code one executor branch per format — layout
-packing, kernel call, and VMEM assumptions scattered between
-``sparse/dispatch.py`` and ``kernels/ops.py``.  This module makes the
-kernel layer uniform: each ``(format, backend)`` pair registers a
+This module keeps layout packing, kernel call and VMEM assumptions in
+one place per kernel: each ``(format, backend)`` pair registers a
 :class:`KernelSpec` bundling
 
   * ``prepare(m, ctx)``  — one-time host-side layout prep (format
@@ -19,9 +17,8 @@ kernel layer uniform: each ``(format, backend)`` pair registers a
 
 ``repro.sparse.dispatch.Dispatcher.executor`` resolves the winning plan
 through :func:`get`; ``repro.sparse.stream`` replays the bound closure;
-``benchmarks/spmm_suite.py`` validates its format list against
-:func:`formats_for`; and ``repro.core.calibrate`` sweeps every registered
-spec to fit measured compute ceilings.  :func:`spmm` is the one-call
+and ``repro.core.calibrate`` sweeps every registered spec to fit
+measured compute ceilings.  :func:`spmm` is the one-call
 registry entry point for direct use.
 
 Every Pallas kernel is launched with the same scoped VMEM limit,

@@ -1,6 +1,6 @@
 """Where JAX keeps this program's persistent compilation cache.
 
-The entry points (``chip_smoke.py``, ``benchmarks/run.py``,
+The entry points (``chipbench/run.py``, ``chip_smoke.py``,
 ``repro.launch.serve``) call :func:`configure` once at start-up; importing
 ``repro`` never touches the cache.  The rule:
 

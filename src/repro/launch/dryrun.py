@@ -127,7 +127,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     Compiles nothing and allocates no real arrays: the step function is
     traced over abstract state on a production mesh, and the record
     carries the HLO cost analysis plus the sparse-component metadata the
-    roofline analyzer consumes (``benchmarks/run.py`` roofline section).
+    roofline analyzer (``repro.core.analyzer``) consumes.
     """
     from repro.models import attention as ATT
     ATT.set_causal_impl(causal_impl)

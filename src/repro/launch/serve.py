@@ -85,8 +85,7 @@ def build_stream_matrix(structure: str, n: int):
     expert token bucket (repro.models.moe routes tokens into exactly this
     shape; see examples/moe_block_sparse.py).  The rest are the paper's
     Table III regimes at serving scale.  All four come from the shared
-    registry ``repro.core.patterns.serving_suite``, which
-    ``benchmarks/stream.py`` measures.
+    registry ``repro.core.patterns.serving_suite``.
     """
     suite = serving_suite(n)
     if structure not in suite:
@@ -191,8 +190,7 @@ def serve_spmm_stream(args) -> None:
               f"caches) of the same stream: {percall_s * 1e3:.1f} ms vs "
               f"streamed {streamed_s * 1e3:.1f} ms "
               f"({percall_s / max(streamed_s, 1e-12):.1f}x; "
-              f"a warm-cache per-call baseline sits between — see "
-              f"benchmarks/stream.py percall_cached)")
+              f"a warm-cache per-call baseline sits between)")
     print(f"stats: {plan.stats()}")
 
 
@@ -365,8 +363,10 @@ def main():
         serve_spmm_stream(args)
         return
     if not args.arch:
-        ap.error("--arch is required unless --spmm-stream or --engine "
-                 "is set")
+        if args.calibrate:
+            return
+        ap.error("--arch is required unless --spmm-stream, --engine or "
+                 "--calibrate is set")
 
     from repro.configs.base import get_config
     from repro.models import model as M

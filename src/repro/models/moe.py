@@ -56,7 +56,8 @@ def _expert_ffn(w_gate, w_up, w_down, buf: jnp.ndarray) -> jnp.ndarray:
     """buf: [E_loc, C, d] -> [E_loc, C, d] (SwiGLU), batched over experts.
 
     This is the block-diagonal BCSR SpMM; on TPU it maps to
-    kernels.grouped_matmul with group blocks of C rows.
+    kernels.grouped_matmul.grouped_matmul_pallas with group blocks of C
+    rows.
     """
     h = jax.nn.silu(jnp.einsum("ecd,edf->ecf", buf, w_gate)) * \
         jnp.einsum("ecd,edf->ecf", buf, w_up)

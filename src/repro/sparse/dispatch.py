@@ -10,9 +10,8 @@ system's dispatch layer:
 
 For each candidate format (CSR / ELL / BCSR / DIA) the dispatcher
 
-  1. applies the *applicability policy* (the SpChar-style structural gates
-     that previously lived as ad-hoc heuristics in benchmarks/spmm_suite.py),
-     emitting a skip reason when a format is rejected;
+  1. applies the *applicability policy* (the SpChar-style structural
+     gates), emitting a skip reason when a format is rejected;
   2. evaluates the candidate's sparsity-aware arithmetic intensity on the
      active HardwareSpec: B-traffic from the detected structural regime
      (Section III models), A-traffic from the format's actual storage;

@@ -24,9 +24,7 @@ Two things distinguish this from calling ``sparse.spmm`` per batch:
 2. **Zero-dispatch replay.**  ``execute`` holds the bound kernel closure
    from ``Dispatcher.executor`` — no classification, no plan-cache or
    conversion-cache lookups, no policy checks per call.  Per-call dispatch
-   pays those on every batch; the streamed benchmark
-   (``benchmarks/stream.py``) measures the gap across the four paper
-   sparsity structures.
+   pays those on every batch.
 """
 from __future__ import annotations
 

@@ -1,16 +1,15 @@
-"""Test bootstrap: make ``repro`` (src layout) and ``benchmarks``
-importable regardless of how pytest is invoked, and isolate the
-calibration store so dispatch predictions never depend on whatever
-``~/.cache/repro/calibrations`` happens to hold on the host."""
+"""Test bootstrap: make ``repro`` (src layout) importable regardless of
+how pytest is invoked, and isolate the calibration store so dispatch
+predictions never depend on whatever ``~/.cache/repro/calibrations``
+happens to hold on the host."""
 import os
 import sys
 import tempfile
 from pathlib import Path
 
-_ROOT = Path(__file__).resolve().parent.parent
-for _p in (str(_ROOT), str(_ROOT / "src")):
-    if _p not in sys.path:
-        sys.path.insert(0, _p)
+_SRC = str(Path(__file__).resolve().parent.parent / "src")
+if _SRC not in sys.path:
+    sys.path.insert(0, _SRC)
 
 os.environ["REPRO_CALIBRATION_DIR"] = tempfile.mkdtemp(
     prefix="repro-cal-test-")

@@ -50,7 +50,7 @@ def test_architecture_modules_exist():
 
 
 def test_architecture_file_paths_exist():
-    """Backticked repo paths (benchmarks/..., tests/, .github/...) too."""
+    """Backticked repo paths (chipbench/..., tests/, .github/...) too."""
     text = "\n".join((DOCS / d).read_text(encoding="utf-8")
                      for d in ("architecture.md", "serving.md"))
     paths = set(re.findall(r"`([A-Za-z0-9_./-]+\.(?:py|yml|md))`", text))

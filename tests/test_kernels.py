@@ -7,20 +7,37 @@ from repro import kernels, sparse
 from repro.core import banded as gen_banded
 from repro.core import blocked as gen_blocked
 from repro.core import erdos_renyi
-from repro.kernels import ref
+from repro.kernels import ref, registry
+from repro.kernels.banded_spmm import banded_spmm_pallas
+from repro.kernels.bcsr_spmm import bcsr_spmm_pallas, pack_blocks
+from repro.kernels.binned_spmm import (binned_spmm_pallas, csr_to_slab_bins,
+                                       pack_rowsplit_chunks,
+                                       rowsplit_spmm_pallas)
+from repro.kernels.csr_spmm import csr_spmm_pallas, csr_to_row_tiles
+from repro.kernels.grouped_matmul import grouped_matmul_pallas
 
-# This module deliberately exercises the deprecated container-level
-# wrappers in repro.kernels.ops (they expose packing knobs — row_tile,
-# chunk, b_tile, block_d — the registry derives itself); the registry
-# path is covered by test_registry / test_differential.  Silence the
-# DeprecationWarning they now raise, except in the explicit test below.
-pytestmark = pytest.mark.filterwarnings("ignore::DeprecationWarning")
+# The sweeps below pack each layout themselves and call the ``*_pallas``
+# kernel, so they reach packing knobs (row_tile, chunk, b_tile, block_d)
+# the registry derives itself; the registry path is covered by
+# test_registry / test_differential.
 
 RNG = np.random.default_rng(0)
 
 
 def _b(n, d, dtype=jnp.float32):
     return jnp.asarray(RNG.normal(size=(n, d))).astype(dtype)
+
+
+def _launch():
+    """What every kernel takes from its caller: this device's scoped
+    VMEM limit, and interpret mode on the CPU backend."""
+    ctx = registry.KernelContext()
+    return {"vmem_limit": ctx.vmem_limit,
+            "interpret": ctx.resolve_interpret()}
+
+
+def _device(arrays):
+    return [jnp.asarray(x) for x in arrays]
 
 
 @pytest.mark.parametrize("t", [16, 32])
@@ -31,7 +48,10 @@ def test_bcsr_kernel_sweep(t, d, block_d, dtype):
     m = gen_blocked(n, t=t, num_blocks=20, nnz_per_block=3 * t, seed=t + d)
     a = sparse.coo_to_bcsr(m, t, dtype=jnp.float32)
     b = _b(n, d, dtype)
-    out = kernels.bcsr_spmm(a, b, block_d=block_d)
+    p = kernels.pad_empty_block_rows(a)
+    out = bcsr_spmm_pallas(pack_blocks(p.blocks, t), p.block_ptr,
+                           p.block_cols, b, n=n, t=t, block_d=block_d,
+                           **_launch())
     expect = ref.bcsr_ref(np.asarray(a.blocks), a.block_rows, a.block_cols,
                           b, n=n, t=t)
     tol = 5e-2 if dtype == jnp.bfloat16 else 5e-4
@@ -46,7 +66,9 @@ def test_bcsr_kernel_empty_rows_padded():
     m = gen_blocked(n, t=t, num_blocks=2, nnz_per_block=20, seed=3)
     a = sparse.coo_to_bcsr(m, t)
     b = _b(n, 8)
-    out = kernels.bcsr_spmm(a, b, block_d=8)
+    p = kernels.pad_empty_block_rows(a)
+    out = bcsr_spmm_pallas(pack_blocks(p.blocks, t), p.block_ptr,
+                           p.block_cols, b, n=n, t=t, block_d=8, **_launch())
     expect = ref.bcsr_ref(np.asarray(a.blocks), a.block_rows, a.block_cols,
                           b, n=n, t=t)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
@@ -94,8 +116,8 @@ def _ring_bcsr(structure, precision, d, block_d, interpret=True, t=16):
     stored (precision-rounded) values and B; returns the packed
     ``(block_ptr, segments, rows)``."""
     from repro.core.precision import as_precision
-    from repro.kernels.bcsr_spmm import (bcsr_segments, bcsr_spmm_pallas,
-                                         pack_bcsr, segment_rows)
+    from repro.kernels.bcsr_spmm import (bcsr_segments, pack_bcsr,
+                                         segment_rows)
     per_row, cap = BLOCK_ROWS[structure]
     n = len(per_row) * t
     prec = as_precision(precision)
@@ -169,8 +191,8 @@ def test_bcsr_kernel_ring_waits_on_every_dma(mode, structure, capfd):
 def test_bcsr_kernel_zeroes_block_rows_it_holds_no_block_for():
     """A block-row pointer with empty rows (no padding): their C tiles
     come out zero, at either end of a segment and inside one."""
-    from repro.kernels.bcsr_spmm import (bcsr_segments, bcsr_spmm_pallas,
-                                         pack_bcsr, segment_rows)
+    from repro.kernels.bcsr_spmm import (bcsr_segments, pack_bcsr,
+                                         segment_rows)
     t, per_row = 16, [0, 3, 0, 0, 5, 0, 2, 0]
     n = len(per_row) * t
     rows, cols, vals = _block_row_matrix(per_row, t, seed=4)
@@ -221,7 +243,6 @@ def test_bcsr_vmem_footprint_is_the_ring(t):
     double-buffered fp32 C tile; at bd = 512 it fits the v5e's kernel
     budget at every block edge."""
     from repro.core.hardware import TPU_V5E
-    from repro.kernels import registry
     from repro.kernels.bcsr_spmm import blocks_per_matmul, ring_slots
     ctx = registry.KernelContext(hardware=TPU_V5E, bcsr_block=t)
     slots, bd = ring_slots(t), 512
@@ -247,7 +268,10 @@ def test_csr_kernel_sweep(pattern, d, block_d):
     m = gen()
     a = sparse.coo_to_csr(m)
     b = _b(n, d)
-    out = kernels.csr_spmm(a, b, row_tile=8, chunk=32, block_d=block_d)
+    tiles = csr_to_row_tiles(a.indptr, a.indices, a.data, n=n, row_tile=8,
+                             chunk=32)
+    out = csr_spmm_pallas(*_device(tiles), b, n=n, row_tile=8,
+                          block_d=block_d, **_launch())
     expect = sparse.coo_to_dense(m) @ b
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
                                rtol=5e-4, atol=5e-4)
@@ -257,12 +281,14 @@ def test_csr_kernel_sweep(pattern, d, block_d):
 def test_csr_kernel_streamed_b_matches_ref(row_tile):
     """B rows DMA'd from HBM into row tiles of any height (incl.
     n % row_tile != 0) match the oracle."""
-    from repro.kernels import ref
     n = 256
     m = erdos_renyi(n, 6, seed=7)
     a = sparse.coo_to_csr(m)
     b = _b(n, 64)
-    out = kernels.csr_spmm(a, b, row_tile=row_tile, chunk=32, block_d=32)
+    tiles = csr_to_row_tiles(a.indptr, a.indices, a.data, n=n,
+                             row_tile=row_tile, chunk=32)
+    out = csr_spmm_pallas(*_device(tiles), b, n=n, row_tile=row_tile,
+                          block_d=32, **_launch())
     expect = ref.csr_ref(a.indptr, a.indices, a.data, b, n=n)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
                                rtol=5e-4, atol=5e-4)
@@ -275,7 +301,6 @@ def test_csr_kernel_streams_past_vmem():
     dispatcher's pallas path still matches the oracle."""
     import dataclasses
     from repro.core.hardware import TPU_V5E
-    from repro.kernels import ref, registry
 
     n, d = 1024, 64
     vmem = 192 * 1024
@@ -312,7 +337,12 @@ def test_binned_kernel_sweep(pattern, b_tile):
          else scale_free(n, 8, alpha=2.05, seed=12))
     a = sparse.coo_to_csr(m)
     b = _b(n, 64)
-    out = kernels.binned_spmm(a, b, chunk=32, block_d=32, b_tile=b_tile)
+    bins = csr_to_slab_bins(a.indptr, a.indices, a.data, n=n,
+                            row_tile=registry.ROW_TILE, chunk=32,
+                            b_tile=b_tile)
+    out = binned_spmm_pallas(*_device(bins), b, n=n,
+                             row_tile=registry.ROW_TILE, b_tile=b_tile,
+                             block_d=32, **_launch())
     expect = ref.csr_ref(a.indptr, a.indices, a.data, b, n=n)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
                                rtol=5e-4, atol=5e-4)
@@ -324,7 +354,6 @@ def test_binned_kernel_streams_past_vmem():
     into multiple B slabs and still match the oracle."""
     import dataclasses
     from repro.core.hardware import TPU_V5E
-    from repro.kernels import registry
 
     n, d = 512, 64
     vmem = 256 * 1024
@@ -365,18 +394,29 @@ def test_binned_kernel_degenerate_bins():
                   vals=np.ones(n, np.float32), pattern="hub_cols")
     a = sparse.coo_to_csr(m)
     b = _b(n, 16)
-    out = kernels.binned_spmm(a, b, chunk=32, block_d=16, b_tile=8)
+
+    def binned(a):
+        bins = csr_to_slab_bins(a.indptr, a.indices, a.data, n=n,
+                                row_tile=registry.ROW_TILE, chunk=32,
+                                b_tile=8)
+        return binned_spmm_pallas(*_device(bins), b, n=n,
+                                  row_tile=registry.ROW_TILE, b_tile=8,
+                                  block_d=16, **_launch())
+
     expect = ref.csr_ref(a.indptr, a.indices, a.data, b, n=n)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
+    np.testing.assert_allclose(np.asarray(binned(a)), np.asarray(expect),
                                rtol=5e-4, atol=5e-4)
 
     empty = COOMatrix(n=n, rows=np.zeros(0, np.int32),
                       cols=np.zeros(0, np.int32),
                       vals=np.zeros(0, np.float32), pattern="empty")
     ae = sparse.coo_to_csr(empty)
-    oute = kernels.binned_spmm(ae, b, chunk=32, block_d=16, b_tile=8)
-    assert not np.any(np.asarray(oute))
-    outr = kernels.rowsplit_spmm(ae, b, chunk=32, block_d=16)
+    assert not np.any(np.asarray(binned(ae)))
+    chunks = pack_rowsplit_chunks(ae.indptr, ae.indices, ae.data, n=n,
+                                  chunk=32)
+    outr = rowsplit_spmm_pallas(*_device(chunks), b, n=n,
+                                window=int(chunks[0].shape[1]), block_d=16,
+                                **_launch())
     assert not np.any(np.asarray(outr))
 
 
@@ -393,7 +433,11 @@ def test_rowsplit_kernel_skewed_rows(chunk):
     m = COOMatrix(n=n, rows=rows, cols=cols, vals=vals, pattern="skew")
     a = sparse.coo_to_csr(m)
     b = _b(n, 32)
-    out = kernels.rowsplit_spmm(a, b, chunk=chunk, block_d=32)
+    chunks = pack_rowsplit_chunks(a.indptr, a.indices, a.data, n=n,
+                                  chunk=chunk)
+    out = rowsplit_spmm_pallas(*_device(chunks), b, n=n,
+                               window=int(chunks[0].shape[1]), block_d=32,
+                               **_launch())
     expect = ref.csr_ref(a.indptr, a.indices, a.data, b, n=n)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
                                rtol=5e-4, atol=5e-4)
@@ -411,7 +455,10 @@ def test_csr_kernel_empty_and_ragged_rows():
         vals=np.ones(53), pattern="custom")
     a = sparse.coo_to_csr(m_coo)
     b = _b(n, 8)
-    out = kernels.csr_spmm(a, b, row_tile=8, chunk=32, block_d=8)
+    tiles = csr_to_row_tiles(a.indptr, a.indices, a.data, n=n, row_tile=8,
+                             chunk=32)
+    out = csr_spmm_pallas(*_device(tiles), b, n=n, row_tile=8, block_d=8,
+                          **_launch())
     expect = sparse.coo_to_dense(m_coo) @ b
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
                                rtol=5e-4, atol=5e-4)
@@ -447,7 +494,6 @@ def _pipelined_csr(structure, precision, d, block_d, interpret=True):
     """The CSR kernel on ``structure`` against the float64 product of the
     stored (precision-rounded) values and B; returns the chunk count."""
     from repro.core.precision import as_precision
-    from repro.kernels.csr_spmm import csr_spmm_pallas, csr_to_row_tiles
     n, row_tile = 128, 8
     prec = as_precision(precision)
     rows, cols, vals = _tiles_matrix(PIPELINE_TILES[structure],
@@ -515,7 +561,6 @@ def test_csr_execute_span_counts_chunks(d):
     """``repro.execute`` carries the CSR kernel's static counts: every
     d-pass reduces every chunk, and only its first goes cold."""
     from repro import obs
-    from repro.kernels import registry
     n = 64
     m = erdos_renyi(n, 6, seed=5)
     disp = sparse.Dispatcher(backend="pallas", calibration=False)
@@ -549,7 +594,7 @@ def test_banded_kernel_sweep(bandwidth, d):
     band, w = kernels.band_to_blocks(np.asarray(dia.data), dia.offsets,
                                      n=n, t=t)
     b = _b(n, d)
-    out = kernels.banded_spmm(band, b, t=t, w=w, block_d=d)
+    out = banded_spmm_pallas(band, b, t=t, w=w, block_d=d, **_launch())
     expect = ref.banded_ref(np.asarray(band), b, t=t, w=w)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
                                rtol=5e-4, atol=5e-4)
@@ -562,7 +607,8 @@ def test_grouped_matmul_sweep(E, bm, dtype):
     x = _b(T, K, dtype)
     w = jnp.asarray(RNG.normal(size=(E, K, N))).astype(dtype)
     gids = jnp.asarray(RNG.integers(0, E, size=T // bm).astype(np.int32))
-    out = kernels.grouped_matmul(x, w, gids, bm=bm, bk=64, bn=128)
+    out = grouped_matmul_pallas(x, w, gids, bm=bm, bk=64, bn=128,
+                                **_launch())
     expect = ref.grouped_matmul_ref(x, w, gids, bm=bm)
     tol = 1e-1 if dtype == jnp.bfloat16 else 2e-3
     np.testing.assert_allclose(np.asarray(out, np.float32),
@@ -576,7 +622,8 @@ def test_grouped_matmul_matches_moe_semantics():
     gids = jnp.asarray([0, 1, 1, 3], jnp.int32)
     x = _b(4 * bm, K)
     w = jnp.asarray(RNG.normal(size=(E, K, N)).astype(np.float32))
-    out = kernels.grouped_matmul(x, w, gids, bm=bm, bk=64, bn=64)
+    out = grouped_matmul_pallas(x, w, gids, bm=bm, bk=64, bn=64,
+                                **_launch())
     for blk in range(4):
         seg = slice(blk * bm, (blk + 1) * bm)
         np.testing.assert_allclose(
@@ -600,33 +647,14 @@ def test_kernel_rooflines():
     assert g.ai > r.ai                # MoE blocks beat generic sparse blocks
 
 
-def test_ops_wrappers_raise_deprecation_warning():
-    """The container-level wrappers warn callers toward the registry."""
-    m = gen_blocked(64, t=16, num_blocks=4, nnz_per_block=20, seed=9)
-    a = sparse.coo_to_bcsr(m, 16)
-    b = _b(64, 8)
-    with pytest.warns(DeprecationWarning, match="registry"):
-        kernels.bcsr_spmm(a, b, block_d=8)
-    with pytest.warns(DeprecationWarning, match="registry"):
-        kernels.csr_spmm(sparse.coo_to_csr(m), b, chunk=32, block_d=8)
-
-
 def test_kernels_take_interpret_and_vmem_limit_from_the_caller():
     """No kernel entry point defaults to interpret mode, and every
     pallas_call states its scoped VMEM limit."""
-    import importlib
     import inspect
     import pathlib
     import re
-    from repro.kernels import registry
-    # The package re-exports wrappers under the module names.
-    mod = lambda name: importlib.import_module(f"repro.kernels.{name}")  # noqa: E731,E501
-    fns = (mod("banded_spmm").banded_spmm_pallas,
-           mod("bcsr_spmm").bcsr_spmm_pallas,
-           mod("binned_spmm").binned_spmm_pallas,
-           mod("binned_spmm").rowsplit_spmm_pallas,
-           mod("csr_spmm").csr_spmm_pallas,
-           mod("grouped_matmul").grouped_matmul_pallas)
+    fns = (banded_spmm_pallas, bcsr_spmm_pallas, binned_spmm_pallas,
+           rowsplit_spmm_pallas, csr_spmm_pallas, grouped_matmul_pallas)
     for fn in fns:
         params = inspect.signature(fn).parameters
         for name in ("interpret", "vmem_limit"):

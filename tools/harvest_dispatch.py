@@ -14,17 +14,18 @@ Closes the SpChar loop (arXiv 2304.06944) over the matrix corpus:
      CI artifact upload).
   3. **Audit** — replan every (matrix, d) pair analytic-only vs
      tree-assisted and emit an agreement CSV
-     (``matrix, impl=tree_vs_analytic, d, agreement, never_worse``)
-     that ``tools/perf_trend.py --metric agreement`` can trend across
-     nightly runs, and check the gated claim: the tree-assisted choice's
+     (``matrix, impl=tree_vs_analytic, d, agreement, never_worse``),
+     and check the gated claim: the tree-assisted choice's
      *measured* GFLOP/s is never below ``--claim-factor`` (default
      0.95) of the analytic-only choice's.  ``--enforce`` turns a claim
      failure into a non-zero exit.
 
 Run from the repo root:
 
-    PYTHONPATH=src python tools/harvest_dispatch.py \
-        --out-dir benchmarks/out/harvest --enforce
+    PYTHONPATH=src python tools/harvest_dispatch.py --enforce
+
+The CSVs and the tree's copy go to ``local/harvest/`` (gitignored)
+unless ``--out-dir`` says otherwise.
 """
 from __future__ import annotations
 
@@ -153,7 +154,7 @@ def main(argv: List[str]) -> int:
     ap.add_argument("--corpus-root", default=None,
                     help="corpus directory (default: $REPRO_CORPUS_DIR "
                          "or the vendored samples)")
-    ap.add_argument("--out-dir", default="benchmarks/out/harvest",
+    ap.add_argument("--out-dir", default="local/harvest",
                     help="where the CSVs + fitted-tree JSON artifact go")
     ap.add_argument("--d", type=int, nargs="+", default=[32, 128],
                     help="dense operand widths to sweep")
